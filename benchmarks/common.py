@@ -14,8 +14,12 @@ SRC = REPO / "src"
 
 
 def run_subprocess(code: str, *, devices: int = 1, timeout: int = 900) -> str:
-    """Run a benchmark snippet on `devices` forced host devices."""
+    """Run a benchmark snippet on `devices` forced host devices.
+
+    The child is pinned to the CPU: on a machine with a TPU it would
+    otherwise claim the chip, which belongs to one process at a time."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(SRC)
     proc = subprocess.run(

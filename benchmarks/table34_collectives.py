@@ -25,17 +25,16 @@ import json, time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed import flat_grad_allreduce, hierarchical_grad_allreduce
-from repro.distributed.collectives import compat_shard_map
-from repro.launch.mesh import make_compat_mesh
 
-mesh = make_compat_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 results = []
 for size_bytes in {_SIZES!r}:
     n = max(size_bytes // 4, 1)
     x = {{"g": jnp.arange(n, dtype=jnp.float32) / n}}
 
     def run_fn(fn):
-        return jax.jit(compat_shard_map(fn, mesh=mesh, in_specs=(P(),),
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(P(),),
                                      out_specs=P(), check_vma=False))
 
     flat = run_fn(lambda t: flat_grad_allreduce(t, data_axis="data", pod_axis="pod"))

@@ -4,7 +4,7 @@ One bundle, three "systems" (platform descriptors), zero modification:
 
   laptop   : reference ops only (no native features)      — build & test
   cluster  : native collectives available                  — deploy
-  pod-v5e  : Pallas kernels + native collectives declared  — deploy
+  tpu-v5e-1chip  : Pallas kernels + native collectives declared  — deploy
 
 For each deployment we print the op-binding report (which ops were
 swapped, which refused and why) and verify the model output is IDENTICAL
@@ -58,11 +58,11 @@ def main() -> None:
     print(f"bundle: {bundle.reference} (digest {bundle.digest})\n")
     losses = {}
     swapped_by_system = {}
-    # pod-v5e declares pallas_kernels but requires an actual TPU ("driver
+    # tpu-v5e-1chip declares pallas_kernels but requires an actual TPU ("driver
     # loaded") — on this CPU host the swap is refused with a report.
     # pod-sim runs the SAME Pallas kernels through the interpreter, so the
     # swap genuinely happens and the numerics can be compared.
-    for system in ("laptop", "cluster", "pod-v5e", "pod-sim"):
+    for system in ("laptop", "cluster", "tpu-v5e-1chip", "pod-sim"):
         loss, swapped, refused = deploy_and_run(bundle, system, params, batch)
         losses[system] = loss
         swapped_by_system[system] = swapped
@@ -74,7 +74,7 @@ def main() -> None:
         print()
 
     assert swapped_by_system["pod-sim"], "pod-sim must swap in the kernels"
-    assert not swapped_by_system["pod-v5e"], "no TPU present -> swap refused"
+    assert not swapped_by_system["tpu-v5e-1chip"], "no TPU present -> swap refused"
     spread = max(losses.values()) - min(losses.values())
     print(f"cross-system loss spread: {spread:.2e} "
           f"(ref vs swapped-kernel numerics agree: {spread < 1e-3})\n")
@@ -92,7 +92,7 @@ def main() -> None:
                provider="bad-vendor"),
         strict=False,
     )
-    binding = reg.bind(["rmsnorm"], PLATFORMS["pod-v5e"], native=True, freeze=False)
+    binding = reg.bind(["rmsnorm"], PLATFORMS["tpu-v5e-1chip"], native=True, freeze=False)
     report = binding.reports[0]
     print("ABI refusal demo (mismatched vendor rmsnorm):")
     print("  registration refused (libtool-string mismatch logged above);")

@@ -53,6 +53,7 @@ class ModelConfig:
     n_patches: int = 0              # vlm: patch embeddings prepended to tokens
     # numerics / training
     dtype: str = "bfloat16"
+    residual_fp32: bool = False     # keep the residual stream in fp32
     remat: str = "full"             # none | dots | full
     tie_embeddings: bool = False
     # free-form provenance notes (source tags from the assignment)

@@ -21,5 +21,6 @@ CONFIG = ModelConfig(
     ssm_expand=2,
     ssm_headdim=64,
     tie_embeddings=True,
+    residual_fp32=True,     # the published config's residual_in_fp32
     notes="[arXiv:2405.21060; unverified] SSD (state-space duality)",
 )

@@ -9,9 +9,7 @@ from repro.core.gateway import Gateway, GatewayError
 from repro.core.platform import (
     CLUSTER,
     LAPTOP,
-    MULTIPOD_V5E,
     PLATFORMS,
-    POD_V5E,
     TPU_V5E,
     HardwareSpec,
     Platform,
@@ -33,7 +31,7 @@ __all__ = [
     "Bundle", "BundleError",
     "parse_visible_devices", "resolve_platform", "select_devices",
     "Gateway", "GatewayError",
-    "CLUSTER", "LAPTOP", "MULTIPOD_V5E", "PLATFORMS", "POD_V5E", "TPU_V5E",
+    "CLUSTER", "LAPTOP", "PLATFORMS", "TPU_V5E",
     "HardwareSpec", "Platform", "detect_platform",
     "ImplKind", "OpBinding", "OpDecl", "OpImpl", "OpRegistry", "SwapReport",
     "global_registry",

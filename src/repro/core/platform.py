@@ -20,11 +20,12 @@ __all__ = [
     "Platform",
     "LAPTOP",
     "CLUSTER",
-    "POD_V5E",
-    "MULTIPOD_V5E",
     "PLATFORMS",
     "detect_platform",
+    "chip_platform",
+    "tpu_hardware",
     "TPU_V5E",
+    "TPU_KINDS",
 ]
 
 
@@ -41,7 +42,8 @@ class HardwareSpec:
     ici_links: int = 4          # links per chip (2D torus -> 4)
 
 
-# Target accelerator for this reproduction (assignment constants).
+# Published per-chip peaks of a TPU v5e (Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI).
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -50,6 +52,13 @@ TPU_V5E = HardwareSpec(
     ici_bandwidth=50e9,
     dcn_bandwidth=25e9 / 8,     # ~25 Gbit/s effective per host, in bytes/s
 )
+
+# TPU chips by the `device_kind` JAX reports.  A kind missing here is an
+# error, never a default: another chip's peaks would silently skew every
+# roofline and tuning decision made against them.
+TPU_KINDS: dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
 
 # Commodity CPU "laptop" — the build-and-test environment of the paper's
 # workflow (Fig. 2 step 1-2).  Constants are nominal; they only matter for
@@ -114,27 +123,6 @@ CLUSTER = Platform(
     description="small multi-device host (8 local devices); flat collectives",
 )
 
-POD_V5E = Platform(
-    name="pod-v5e",
-    hardware=TPU_V5E,
-    mesh_shape=(16, 16),
-    mesh_axes=("data", "model"),
-    native_features=frozenset({"pallas_kernels", "native_collectives"}),
-    description="single TPU v5e pod slice, 256 chips, 2D ICI torus",
-)
-
-MULTIPOD_V5E = Platform(
-    name="multipod-v5e",
-    hardware=TPU_V5E,
-    mesh_shape=(2, 16, 16),
-    mesh_axes=("pod", "data", "model"),
-    native_features=frozenset(
-        {"pallas_kernels", "native_collectives", "hierarchical_collectives",
-         "gradient_compression"}
-    ),
-    description="2 x v5e pod over DCN; hierarchical collectives on the pod axis",
-)
-
 # CPU host that runs the Pallas kernels through the interpreter — used to
 # validate the full swap path (binding reports + numerics) without a TPU.
 POD_SIM = Platform(
@@ -146,9 +134,39 @@ POD_SIM = Platform(
     description="CPU simulation host: Pallas kernels in interpret mode",
 )
 
+
+def chip_platform(hardware: HardwareSpec, n: int) -> Platform:
+    """The site that is exactly the `n` chips present: a flat data mesh."""
+    features = {"pallas_kernels"}
+    if n > 1:
+        features.add("native_collectives")
+    return Platform(
+        name=f"{hardware.name}-{n}chip",
+        hardware=hardware,
+        mesh_shape=(n,),
+        mesh_axes=("data",),
+        native_features=frozenset(features),
+        description=f"{n} attached {hardware.name} chip{'s' if n > 1 else ''}",
+    )
+
+
+# Sites selectable by name (REPRO_PLATFORM): the CPU hosts, and one or
+# four attached v5e chips, which is what detect_platform finds there.
 PLATFORMS: dict[str, Platform] = {
-    p.name: p for p in (LAPTOP, CLUSTER, POD_V5E, MULTIPOD_V5E, POD_SIM)
+    p.name: p for p in (LAPTOP, CLUSTER, POD_SIM,
+                        chip_platform(TPU_V5E, 1), chip_platform(TPU_V5E, 4))
 }
+
+
+def tpu_hardware(device_kind: str) -> HardwareSpec:
+    """Per-chip constants for a TPU `device_kind`; unknown kinds raise."""
+    try:
+        return TPU_KINDS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"TPU device_kind {device_kind!r} has no hardware entry; "
+            f"known: {sorted(TPU_KINDS)}"
+        ) from None
 
 
 def detect_platform(devices: Sequence[jax.Device] | None = None) -> Platform:
@@ -156,13 +174,14 @@ def detect_platform(devices: Sequence[jax.Device] | None = None) -> Platform:
 
     Order of precedence mirrors Shifter: explicit environment request
     (handled by env.resolve_platform, which calls this as fallback), then
-    device inspection.
+    device inspection.  TPU devices give the platform of the chips
+    actually present, keyed by their `device_kind`.
     """
     devices = list(devices if devices is not None else jax.devices())
     kind = devices[0].platform if devices else "cpu"
     n = len(devices)
     if kind == "tpu":
-        return MULTIPOD_V5E if n > 256 else POD_V5E
+        return chip_platform(tpu_hardware(devices[0].device_kind), n)
     if n >= 8:
         return CLUSTER
     return LAPTOP

@@ -54,7 +54,7 @@ log = logging.getLogger("repro.runtime")
 # Host variables a container inherits (Shifter: "selected variables from the
 # host system are also added", per site configuration).
 _HOST_ENV_ALLOWLIST = (ENV_VISIBLE, "REPRO_PLATFORM", "REPRO_CHECKPOINT_DIR",
-                       "REPRO_COMPILE_CACHE", "REPRO_AUTOTUNE",
+                       "REPRO_AUTOTUNE",
                        "REPRO_TUNING_CACHE", "REPRO_PROFILE",
                        "REPRO_WORKLOAD_PROFILE", "REPRO_SEARCH_BUDGET",
                        "REPRO_TUNING_MAX_ENTRIES", "REPRO_TUNING_MAX_BYTES",

@@ -1,7 +1,6 @@
 """Distribution layer: sharding rules, tiered collectives, pipeline."""
 
 from repro.distributed.collectives import (
-    compat_shard_map,
     flat_grad_allreduce,
     hierarchical_grad_allreduce,
     make_grad_sync,
@@ -18,7 +17,6 @@ from repro.distributed.sharding import (
 )
 
 __all__ = [
-    "compat_shard_map",
     "flat_grad_allreduce", "hierarchical_grad_allreduce", "make_grad_sync",
     "pipeline_apply",
     "BASELINE_RULES", "ShardingRules", "batch_spec", "cache_specs",

@@ -31,36 +31,14 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.quant import compress_int8
 
 __all__ = [
-    "compat_shard_map",
     "flat_grad_allreduce",
     "hierarchical_grad_allreduce",
     "make_grad_sync",
 ]
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """shard_map across jax versions: top-level `jax.shard_map` (with
-    ``check_vma``) where it exists, else the 0.4.x
-    ``jax.experimental.shard_map`` (whose equivalent knob is ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
 def _pmean_tree(tree: Any, axes) -> Any:
     return jax.tree.map(lambda g: jax.lax.pmean(g, axes), tree)
-
-
-def _axis_size(axis_name: str):
-    """jax.lax.axis_size where it exists (newer jax); psum(1) is the
-    version-agnostic spelling of the same quantity inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def flat_grad_allreduce(grads: Any, *, data_axis: str = "data",
@@ -91,7 +69,7 @@ def hierarchical_grad_allreduce(
 
     def one(g: jnp.ndarray) -> jnp.ndarray:
         flat = g.reshape(-1)
-        n = _axis_size(data_axis)
+        n = jax.lax.axis_size(data_axis)
         pad = (-flat.size) % n
         if pad:
             flat = jnp.pad(flat, (0, pad))
@@ -108,7 +86,7 @@ def hierarchical_grad_allreduce(
         # ICI: all-gather the reduced shards back
         full = jax.lax.all_gather(shard, data_axis, axis=0, tiled=True)
         # sum -> mean over the full DP group
-        total = _axis_size(data_axis) * _axis_size(pod_axis)
+        total = jax.lax.axis_size(data_axis) * jax.lax.axis_size(pod_axis)
         return (full[: g.size].reshape(g.shape) / total).astype(g.dtype)
 
     return jax.tree.map(one, grads)

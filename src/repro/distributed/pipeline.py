@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.collectives import compat_shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -73,7 +72,7 @@ def pipeline_apply(
         return jax.lax.psum(outputs, axis)
 
     param_specs = jax.tree.map(lambda _: P(axis), stage_params)
-    return compat_shard_map(
+    return jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(param_specs, P()),

@@ -1,11 +1,20 @@
 """Flash attention (causal, GQA) — Pallas TPU kernel.
 
-TPU adaptation of the flash algorithm: the grid is (batch, q_heads,
-q_blocks, kv_blocks) with the kv dimension minor — TPU grids execute the
-minor dimension sequentially on a core, so the running softmax state
-(m, l, acc) lives in VMEM scratch and is carried across kv steps without
-HBM traffic.  Block shapes default to (128, head_dim): MXU-aligned and
-small enough that q/k/v tiles + scratch fit VMEM for head_dim <= 256.
+TPU adaptation of the flash algorithm: the grid is (batch, q_blocks,
+kv_blocks) with the kv dimension minor — TPU grids execute the minor
+dimension sequentially on a core, so the running softmax state (m, l,
+acc) of every head lives in VMEM scratch and is carried across kv steps
+without HBM traffic.
+
+Every block spans the operands' full trailing (heads, head_dim) pair —
+q/out tiles are (block_q, H, Dh), k/v tiles (block_k, KV, Dh) — because
+Mosaic only accepts blocks whose last two dimensions are (8, 128)-aligned
+or whole, and a single head (1, Dh) is neither.  The kernel body loops
+over the KV heads statically and, inside, over the query heads of each
+GQA group, so each k/v tile is fetched from HBM once per q-block and read
+by all of its query heads; the per-head math is unchanged.  Block shapes
+default to (128, ·): MXU-aligned and small enough that the tiles plus
+scratch fit VMEM at head_dim 128 and 32 query heads.
 
 Causal blocks strictly above the diagonal are skipped with pl.when — for
 long sequences this halves the executed grid.  Two scalar rows ride in
@@ -51,10 +60,9 @@ before the softmax, so stale contents are inert.
 
 **Quantized KV cache** (``k_scale``/``v_scale``): k/v (contiguous or
 paged pools) may be stored int8 or fp8 with per-batch float32
-dequantization scales.  The scales ride the same int32 SMEM meta as
-kv_len/q_start/ws — their fp32 bits reinterpreted via
-``jax.lax.bitcast_convert_type`` on the way in and bitcast back inside
-the kernel — so the scalar-prefetch ABI stays single-dtype.  Tiles are
+dequantization scales.  The scales are a (2, B) fp32 operand held
+whole in SMEM (row 0 k, row 1 v), beside the int32 scalar-prefetch
+meta.  Tiles are
 dequantized in VMEM after the DMA: the cache streams from HBM at one
 byte per element, the softmax math stays fp32
 (docs/quantization.md pins the per-format error envelopes).
@@ -80,17 +88,12 @@ _NEG_INF = -1e30
 
 
 def _flash_kernel(
-    meta_ref,       # SMEM (2[+1][+2], B) int32: row 0 kv_len, row 1 q_start,
-                    # then window start (windowed only), then the fp32
-                    # k/v scales bitcast to int32 (quantized KV only)
-    q_ref,          # (1, bq, 1, dh)
-    k_ref,          # (1, bk, 1, dh)
-    v_ref,          # (1, bk, 1, dh)
-    o_ref,          # (1, bq, 1, dh)
-    m_ref,          # scratch (bq,)
-    l_ref,          # scratch (bq,)
-    acc_ref,        # scratch (bq, dh)
-    *,
+    meta_ref,       # SMEM (2[+1], B) int32: row 0 kv_len, row 1 q_start,
+                    # then window start (windowed only), then block tables
+    *refs,          # [scale_ref: SMEM (2, B) fp32 k/v scales, quantized
+                    # only], q (1, bq, H, dh), k (1, bk, KV, dh),
+                    # v (1, bk, KV, dh), out (1, bq, H, dh), then scratch:
+                    # m (bq, H), l (bq, H), acc (H, bq, dh)
     causal: bool,
     scale: float,
     block_q: int,
@@ -99,20 +102,23 @@ def _flash_kernel(
     q_offset: int,      # sk - sq: static diagonal for the skip heuristic
     dyn_offset: bool,   # True when q_start is a traced value (chunk prefill)
     windowed: bool,     # True when meta carries a window-start row
-    quantized: bool,    # True when meta carries bitcast k/v scale rows
+    quantized: bool,    # True when a k/v scale operand precedes q
+    group: int,         # query heads per KV head
 ):
+    if quantized:
+        scale_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     bi = pl.program_id(0)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq = pl.program_id(1)
+    ik = pl.program_id(2)
     kvl = meta_ref[0, bi]
     qs = meta_ref[1, bi]
     ws = meta_ref[2, bi] if windowed else None
     if quantized:
-        # the scales ride the int32 SMEM meta bit-exactly: fp32 bits in,
-        # fp32 bits out (docs/quantization.md, "kernel meta ABI")
-        srow = 3 if windowed else 2
-        ksc = jax.lax.bitcast_convert_type(meta_ref[srow, bi], jnp.float32)
-        vsc = jax.lax.bitcast_convert_type(meta_ref[srow + 1, bi], jnp.float32)
+        ksc = scale_ref[0, bi]
+        vsc = scale_ref[1, bi]
+    n_kv = k_ref.shape[2]
 
     @pl.when(ik == 0)
     def _init():
@@ -138,17 +144,6 @@ def _flash_kernel(
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            # dequantize the 1-byte cache tiles in VMEM: k/v stream from
-            # HBM at a quarter of the fp32 bytes, math stays fp32
-            k = k * ksc
-            v = v * vsc
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (bq, bk)
         mask = k_pos < kvl
         if causal:
             mask = mask & (k_pos <= q_pos + qs)
@@ -156,26 +151,44 @@ def _flash_kernel(
             # sliding window: query q_pos attends keys >= its own window
             # start ws + q_pos (the mirror image of the causal bound)
             mask = mask & (k_pos >= q_pos + ws)
-        s = jnp.where(mask, s, _NEG_INF)
         # rows past kv_len may be out-of-bounds tile padding (garbage, NaN
         # in interpret mode); p is 0 there but 0 * NaN = NaN, so zero v too
         k_row = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
-        v = jnp.where(k_row < kvl, v, 0.0)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
+        # static head loops: Mosaic indexes the second-minor (head) axis of
+        # a tile only at static offsets
+        for kvh in range(n_kv):
+            k = k_ref[0, :, kvh, :].astype(jnp.float32)
+            v = v_ref[0, :, kvh, :].astype(jnp.float32)
+            if quantized:
+                # dequantize the 1-byte cache tiles in VMEM: k/v stream from
+                # HBM at a quarter of the fp32 bytes, math stays fp32
+                k = k * ksc
+                v = v * vsc
+            v = jnp.where(k_row < kvl, v, 0.0)
+            for h in range(kvh * group, (kvh + 1) * group):
+                q = q_ref[0, :, h, :].astype(jnp.float32) * scale
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # (bq, bk)
+                s = jnp.where(mask, s, _NEG_INF)
+                m_prev = m_ref[:, h:h + 1]
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_ref[:, h:h + 1] = (l_ref[:, h:h + 1] * alpha
+                                     + p.sum(axis=-1, keepdims=True))
+                acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                m_ref[:, h:h + 1] = m_new
 
     @pl.when(ik == kv_blocks - 1)
     def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        for h in range(o_ref.shape[2]):
+            denom = jnp.maximum(l_ref[:, h:h + 1], 1e-30)
+            o_ref[0, :, h, :] = (acc_ref[h] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -247,14 +260,7 @@ def flash_attention(
         # base is kv_len - sq.
         w = jnp.broadcast_to(jnp.asarray(window, jnp.int32), (b,))
         rows.append(kv_len - sq - w + 1)
-    if quantized:
-        # the dequantization scales ride the same int32 SMEM meta: fp32
-        # bits reinterpreted, bitcast back inside the kernel (the meta
-        # stack must stay single-dtype for jnp.stack)
-        for s in (k_scale, v_scale):
-            s32 = jnp.broadcast_to(jnp.asarray(s, jnp.float32), (b,))
-            rows.append(jax.lax.bitcast_convert_type(s32, jnp.int32))
-    meta = jnp.stack(rows)                       # (2 [+1] [+2], B) in SMEM
+    meta = jnp.stack(rows)                       # (2 [+1], B) in SMEM
     tbl_row = len(rows)                          # first block-table meta row
     if paged:
         # block-table rows ride below the scalar rows: meta[tbl_row+j, bi]
@@ -274,47 +280,49 @@ def flash_attention(
         dyn_offset=dyn_offset,
         windowed=windowed,
         quantized=quantized,
+        group=group,
     )
     if paged:
         bpp = page // block_k                    # k-tiles per page
 
         def kv_spec():
             return pl.BlockSpec(
-                (1, block_k, 1, dh),
+                (1, block_k, kv, dh),
                 # logical k-block ik lives in page meta[tbl_row + ik // bpp,
                 # bi], tile ik % bpp within it — the DMA performs the gather
-                lambda bi, hi, iq, ik, m: (m[tbl_row + ik // bpp, bi],
-                                           ik % bpp, hi // group, 0),
+                lambda bi, iq, ik, m: (m[tbl_row + ik // bpp, bi],
+                                       ik % bpp, 0, 0),
             )
     else:
         def kv_spec():
             return pl.BlockSpec(
-                (1, block_k, 1, dh),
-                lambda bi, hi, iq, ik, kvl: (bi, ik, hi // group, 0),
+                (1, block_k, kv, dh), lambda bi, iq, ik, m: (bi, ik, 0, 0)
             )
-    grid = (b, h, q_blocks, kv_blocks)
+    q_spec = pl.BlockSpec((1, block_q, h, dh), lambda bi, iq, ik, m: (bi, iq, 0, 0))
+    in_specs = [q_spec, kv_spec(), kv_spec()]
+    operands = [q, k, v]
+    if quantized:
+        # the fp32 dequantization scales sit whole in SMEM, one column
+        # per batch row: row 0 k, row 1 v
+        in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.insert(0, jnp.stack([
+            jnp.broadcast_to(jnp.asarray(s, jnp.float32), (b,))
+            for s in (k_scale, v_scale)
+        ]))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(
-                    (1, block_q, 1, dh), lambda bi, hi, iq, ik, kvl: (bi, iq, hi, 0)
-                ),
-                kv_spec(),
-                kv_spec(),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, block_q, 1, dh), lambda bi, hi, iq, ik, kvl: (bi, iq, hi, 0)
-            ),
+            grid=(b, q_blocks, kv_blocks),
+            in_specs=in_specs,
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q,), jnp.float32),
-                pltpu.VMEM((block_q, dh), jnp.float32),
+                pltpu.VMEM((block_q, h), jnp.float32),
+                pltpu.VMEM((block_q, h), jnp.float32),
+                pltpu.VMEM((h, block_q, dh), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, sq, h, dh), q.dtype),
         interpret=interpret,
-    )(meta, q, k, v)
+    )(meta, *operands)
     return out

@@ -120,8 +120,8 @@ _SIGS = {
 #   decode_attention 4 / chunk_attention 3: optional trailing
 #              k_scale/v_scale args (traced () or (B,) f32) — k/v caches
 #              may be int8/fp8 quantized pools, dequantized in-kernel
-#              after the VMEM upcast; the scales ride the same SMEM meta
-#              as the kv_len/window rows, fp32 bits bitcast to int32
+#              after the VMEM upcast; the scales are a (2, B) fp32
+#              SMEM operand beside the int32 meta
 #              (docs/quantization.md "scale meta ABI")
 _ABI_MINORS = {"moe_gmm": 2, "decode_attention": 4, "chunk_attention": 3}
 

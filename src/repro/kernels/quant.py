@@ -19,8 +19,8 @@ Two formats (docs/quantization.md):
     conformance grid pins the looser envelope.
 
 Scales are always float32 and always strictly positive (the ``EPS``
-floor), so dequantization never divides by zero and the attention
-kernels can bitcast them through int32 SMEM meta rows losslessly.
+floor), so dequantization never divides by zero; the attention
+kernels read them from an fp32 SMEM operand.
 """
 
 from __future__ import annotations

@@ -36,7 +36,7 @@ def _quant_matmul_kernel(x_ref, qw_ref, scale_ref, o_ref):
         x, w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    o_ref[...] = (acc * scale_ref[...][None, :]).astype(o_ref.dtype)
+    o_ref[...] = (acc * scale_ref[...]).astype(o_ref.dtype)   # (1, bn) row
 
 
 @functools.partial(
@@ -79,10 +79,11 @@ def quant_matmul(
         in_specs=[
             pl.BlockSpec((block_m, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, block_n), lambda i, j: (0, j)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
+            # 2-D (1, F) scale row: Mosaic cannot tile a 1-D operand
+            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, f), x.dtype),
         interpret=interpret,
-    )(x, qw, scale)
+    )(x, qw, scale.reshape(1, f))
     return out

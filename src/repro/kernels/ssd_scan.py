@@ -30,8 +30,8 @@ _DEFAULTS = default_config("ssd_scan")   # single source of truth for fallbacks
 
 def _ssd_kernel(
     x_ref,       # (1, 1, 1, Q, P)
-    dt_ref,      # (1, 1, 1, Q)
-    a_ref,       # (1,)
+    row_ref,     # (1, 1, 1, 2, Q)  [dt; cumsum(dt*A)] as rows
+    col_ref,     # (1, 1, 1, Q, 2)  [dt | cumsum(dt*A)] as columns
     b_ref,       # (1, 1, 1, Q, N)
     c_ref,       # (1, 1, 1, Q, N)
     y_ref,       # (1, 1, 1, Q, P)
@@ -47,38 +47,42 @@ def _ssd_kernel(
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)        # (Q,)
-    a = a_ref[0].astype(jnp.float32)                # ()
     bm = b_ref[0, 0, 0].astype(jnp.float32)         # (Q, N)
     cm = c_ref[0, 0, 0].astype(jnp.float32)         # (Q, N)
-
-    dA = dt * a                                     # (Q,) log decay
-    dA_cum = jnp.cumsum(dA)                         # inclusive
+    # dt and the inclusive log-decay cumsum (computed by the wrapper)
+    # arrive as 2-D rows and columns: Mosaic cannot tile a (Q,) vector
+    dt_row = row_ref[0, 0, 0, 0:1, :]               # (1, Q)
+    cum_row = row_ref[0, 0, 0, 1:2, :]              # (1, Q)
+    dt_col = col_ref[0, 0, 0, :, 0:1]               # (Q, 1)
+    cum_col = col_ref[0, 0, 0, :, 1:2]              # (Q, 1)
 
     # intra-chunk dual form
-    diff = dA_cum[:, None] - dA_cum[None, :]        # (Q, Q)
+    diff = cum_col - cum_row                        # (Q, Q)
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     kj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     decay = jnp.exp(jnp.where(qi >= kj, diff, -jnp.inf))  # mask pre-exp
     scores = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                               # (Q, Q)
-    m = scores * decay * dt[None, :]
+    m = scores * decay * dt_row
     y = jax.lax.dot_general(
         m, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                               # (Q, P)
 
     # contribution of the carried state
     state = state_ref[...]                          # (N, P)
-    c_decay = cm * jnp.exp(dA_cum)[:, None]
+    c_decay = cm * jnp.exp(cum_col)
     y = y + jax.lax.dot_general(
         c_decay, state, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     # state update: decay to end of chunk
-    decay_to_end = jnp.exp(dA_cum[-1] - dA_cum)     # (Q,)
-    wb = bm * (decay_to_end * dt)[:, None]          # (Q, N)
-    new_state = state * jnp.exp(dA_cum[-1]) + jax.lax.dot_general(
+    # the chunk's total log decay as a 0-d value: a (1, 1) slice at lane
+    # Q-1 cannot be broadcast over sublanes and lanes at once
+    last = jnp.max(cum_row[:, chunk - 1:chunk])     # ()
+    decay_to_end = jnp.exp(last - cum_col)          # (Q, 1)
+    wb = bm * (decay_to_end * dt_col)               # (Q, N)
+    new_state = state * jnp.exp(last) + jax.lax.dot_general(
         wb, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                               # (N, P)
     state_ref[...] = new_state
@@ -114,7 +118,11 @@ def ssd_scan(
 
     # (B, H, NC, Q, ...) layouts so the chunk axis is a clean grid dim
     xr = x.transpose(0, 2, 1, 3).reshape(b, h, nc, chunk, p)
-    dtr = dt.transpose(0, 2, 1).reshape(b, h, nc, chunk)
+    dtr = dt.astype(jnp.float32).transpose(0, 2, 1).reshape(b, h, nc, chunk)
+    # per-chunk inclusive cumsum of the log decay dt*A (A folds in here)
+    cum = jnp.cumsum(dtr * A.astype(jnp.float32)[None, :, None, None], axis=-1)
+    rows = jnp.stack([dtr, cum], axis=3)            # (b, h, nc, 2, chunk)
+    cols = jnp.stack([dtr, cum], axis=4)            # (b, h, nc, chunk, 2)
     br = Bm.transpose(0, 2, 1, 3).reshape(b, g, nc, chunk, n)
     cr = Cm.transpose(0, 2, 1, 3).reshape(b, g, nc, chunk, n)
 
@@ -123,8 +131,8 @@ def ssd_scan(
         grid=(b, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec((1, 1, 1, 2, chunk), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
+            pl.BlockSpec((1, 1, 1, chunk, 2), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
             pl.BlockSpec(
                 (1, 1, 1, chunk, n),
                 lambda bi, hi, ci: (bi, hi // heads_per_group, ci, 0, 0),
@@ -144,7 +152,7 @@ def ssd_scan(
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xr, dtr, A, br, cr)
+    )(xr, rows, cols, br, cr)
 
     y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     return y, st

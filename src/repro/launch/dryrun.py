@@ -1,14 +1,16 @@
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: prove every (arch x shape x mesh) deployment is
 coherent — lower + compile under the production mesh, record memory and
 cost analysis and the collective schedule.
 
-The two lines above MUST precede any jax import: the 512 placeholder
-host devices let jax.make_mesh build the production meshes on this CPU
-container.  Smoke tests and benchmarks do NOT import this module.
+The lines above MUST precede any jax import: the 512 placeholder host
+devices let jax.make_mesh build the production meshes on the CPU, and
+the CPU pin keeps the dry run (and every child it spawns, which inherits
+the environment) off any attached TPU.  Smoke tests and benchmarks do NOT import this module.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2-72b --shape train_4k
@@ -261,7 +263,8 @@ def _run_all(args) -> int:
             cmd += ["--rules", args.rules]
         print(f"=== {arch} x {shape} ({'multi' if args.multi_pod else 'single'}) ===",
               flush=True)
-        proc = subprocess.run(cmd, timeout=args.timeout)
+        proc = subprocess.run(cmd, timeout=args.timeout,
+                              env={**os.environ, "JAX_PLATFORMS": "cpu"})
         if proc.returncode != 0:
             failures += 1
             fname.write_text(json.dumps({

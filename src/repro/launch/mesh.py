@@ -9,24 +9,18 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_compat_mesh"]
+__all__ = ["make_production_mesh", "make_host_mesh"]
 
 
-def make_compat_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    # jax.sharding.AxisType landed after 0.4.37; Auto is the default
-    # there anyway, so omit the kwarg on versions that lack it
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1) -> jax.sharding.Mesh:
@@ -37,5 +31,5 @@ def make_host_mesh(*, data: int | None = None, model: int = 1) -> jax.sharding.M
         data = n // model
     assert data * model <= n, (data, model, n)
     if model > 1:
-        return make_compat_mesh((data, model), ("data", "model"))
-    return make_compat_mesh((data,), ("data",))
+        return _auto_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data,), ("data",))

@@ -54,13 +54,13 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.core import Runtime
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import DeployOptions, make_deployment
-from repro.launch.train import make_bundle
+from repro.launch.train import make_bundle, serving_config
 
 __all__ = ["BlockAllocator", "PagedPool", "Request", "Scheduler", "JaxEngine",
            "Server", "SERVING_STATS_SCHEMA", "DeploymentRejected",
@@ -363,23 +363,29 @@ class JaxEngine:
         if (memory_budget is not None
                 and self.footprint["total_bytes"] > memory_budget):
             raise DeploymentRejected(self.footprint, memory_budget)
-        params = self.model.init(jax.random.PRNGKey(0))
+        # params and cache are created by compiled programs on the
+        # container's own devices: nothing lands on the default device
+        # first, and no fp32 init temporary outlives its leaf
+        mesh = container.mesh
+        whole = NamedSharding(mesh, PartitionSpec())
+        params = jax.jit(self.model.init, out_shardings=self.dep.param_sharding)(
+            jax.random.PRNGKey(0))
         if quantize is not None:
             from repro.checkpoint.manifest import quantize_tree
 
             # storage-form {"q", "scale"} subtrees no longer match the
-            # per-leaf sharding tree, so quantized serving keeps default
-            # placement (the single-host serving path)
-            self.params = jax.tree.map(jnp.asarray,
-                                       quantize_tree(params, quantize))
+            # per-leaf sharding tree, so quantized serving keeps them
+            # whole on the mesh (the single-host serving path)
+            self.params = jax.device_put(quantize_tree(params, quantize), whole)
         else:
-            self.params = jax.device_put(params, self.dep.param_sharding)
+            self.params = params
         if paged:
-            self.cache = self.model.init_paged_cache(
-                self.pool.num_pages, chunk, slots
-            )
+            self.cache = jax.jit(
+                lambda: self.model.init_paged_cache(self.pool.num_pages, chunk, slots),
+                out_shardings=whole)()
         else:
-            self.cache = self.model.init_cache(slots, max_len)
+            self.cache = jax.jit(lambda: self.model.init_cache(slots, max_len),
+                                 out_shardings=whole)()
         self._prefill = jax.jit(self.model.prefill_into)
         self._decode = jax.jit(self.model.decode)
         self.prefill_calls = 0
@@ -922,6 +928,10 @@ class Server:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-14b")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="serve the published widths in the config's own "
+                         "dtype, cut to the first N layers (default: the "
+                         "reduced CPU config)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--max-len", type=int, default=64)
@@ -983,15 +993,17 @@ def main(argv=None) -> int:
                          "here with zero searches")
     args = ap.parse_args(argv)
 
-    bundle = make_bundle(args.arch, reduced=True)
+    enable_compile_cache()
+    reduced = args.layers is None
+    bundle = make_bundle(args.arch, reduced=reduced, num_layers=args.layers)
     runtime = Runtime()
-    container = runtime.deploy(bundle, mesh=make_host_mesh(data=1),
+    container = runtime.deploy(bundle, devices=jax.devices()[:1],
                                native_ops=True if args.native_ops else None,
                                profile=True if args.profile else None,
                                autotune=True if args.autotune else None,
                                max_tuned_entries=args.max_tuned_entries,
                                tuning_bundle=args.tuning_bundle)
-    cfg = get_config(args.arch).reduced()
+    cfg = serving_config(args.arch, reduced=reduced, num_layers=args.layers)
 
     try:
         server = Server(cfg, container, slots=args.slots, max_len=args.max_len,

@@ -27,21 +27,23 @@ import numpy as np
 
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from repro.configs import SHAPES, get_config
-from repro.configs.base import ShapeConfig
+from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core import Bundle, Runtime, global_registry
 from repro.data import DataConfig, SyntheticStream
 from repro.kernels.ops import OP_NAMES, register_all
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import DeployOptions, make_deployment
 from repro.optim import OptState, adamw_init
 
-__all__ = ["main", "train_loop", "make_bundle"]
+__all__ = ["main", "train_loop", "make_bundle", "serving_config"]
 
 
-def make_bundle(arch: str, *, reduced: bool = False) -> Bundle:
-    cfg = get_config(arch)
-    if reduced:
-        cfg = cfg.reduced()
+def make_bundle(arch: str, *, reduced: bool = False,
+                num_layers: int | None = None) -> Bundle:
+    """The portable bundle for `arch`: its reduced CPU config, or the
+    published widths, optionally cut to the first `num_layers` layers."""
+    cfg = serving_config(arch, reduced=reduced, num_layers=num_layers)
     register_all()
     return Bundle(
         name=cfg.name,
@@ -51,6 +53,18 @@ def make_bundle(arch: str, *, reduced: bool = False) -> Bundle:
         required_ops={op: str(global_registry.decl(op).abi) for op in OP_NAMES},
         env={"REPRO_BUNDLE_KIND": "train"},
     )
+
+
+def serving_config(arch: str, *, reduced: bool = False,
+                   num_layers: int | None = None) -> ModelConfig:
+    """`arch`'s registry config: reduced for the CPU, or at published
+    widths and dtype with the stack cut to `num_layers` (None: whole)."""
+    cfg = get_config(arch)
+    if reduced:
+        return cfg.reduced()
+    if num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
+    return cfg
 
 
 def train_loop(
@@ -121,6 +135,7 @@ def main(argv=None) -> int:
                          "REPRO_TUNING_BUNDLE)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     bundle = make_bundle(args.arch, reduced=args.reduced)
     runtime = Runtime()
     mesh = make_host_mesh(data=args.data_mesh or None)
@@ -130,8 +145,6 @@ def main(argv=None) -> int:
                                max_tuned_entries=args.max_tuned_entries,
                                tuning_bundle=args.tuning_bundle)
     print(container.describe())
-
-    from repro.configs.base import ModelConfig
 
     cfg = ModelConfig.from_dict(container.bundle.model_config)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")

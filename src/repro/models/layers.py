@@ -146,14 +146,17 @@ def norm_schema(cfg: ModelConfig, d: int | None = None) -> dict[str, LeafSpec]:
 
 
 def norm_apply(params, x, cfg: ModelConfig, binding, eps: float = 1e-6):
+    """Normalize `x` into the compute dtype (an fp32 residual stream,
+    `cfg.residual_fp32`, is read here and cast down on the way out)."""
+    cdt = jnp.dtype(cfg.dtype)
     if cfg.norm == "rmsnorm":
-        return binding["rmsnorm"](x, params["scale"], eps=eps)
+        return binding["rmsnorm"](x, params["scale"], eps=eps).astype(cdt)
     xf = x.astype(jnp.float32)
     mu = xf.mean(-1, keepdims=True)
     var = ((xf - mu) ** 2).mean(-1, keepdims=True)
     y = (xf - mu) * jax.lax.rsqrt(var + eps)
     y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
-    return y.astype(x.dtype)
+    return y.astype(cdt)
 
 
 # --------------------------------------------------------------------------- #
@@ -165,7 +168,8 @@ def attention_schema(cfg: ModelConfig, n_heads: int | None = None) -> dict[str, 
         "wq": LeafSpec((d, h, dh), ("embed", "heads", "head_dim"), init="scaled"),
         "wk": LeafSpec((d, kv, dh), ("embed", "kv_heads", "head_dim"), init="scaled"),
         "wv": LeafSpec((d, kv, dh), ("embed", "kv_heads", "head_dim"), init="scaled"),
-        "wo": LeafSpec((h, dh, d), ("heads", "head_dim", "embed"), init="scaled"),
+        "wo": LeafSpec((h, dh, d), ("heads", "head_dim", "embed"), init="scaled",
+                       fan_in_dims=2),
     }
     if cfg.qkv_bias:
         leaves["bq"] = LeafSpec((h, dh), ("heads", "head_dim"), init="zeros")

@@ -302,9 +302,9 @@ class Model:
         out: dict = {}
         for pj, entry in cache.items():
             for name, buf in entry.items():
-                arr = np.asarray(buf)
-                out[f"{pj}/{name}"] = (arr[:, pages] if name in ("k", "v")
-                                       else arr[:, slot])
+                # gather on the device: only the slot's pages cross to host
+                out[f"{pj}/{name}"] = np.asarray(
+                    buf[:, pages] if name in ("k", "v") else buf[:, slot])
         return out
 
     def import_paged_slot(self, cache: Tree, arrays: Mapping[str, Any],
@@ -461,6 +461,8 @@ class Model:
         p = self.period
         unroll = self.num_blocks if self.scan_unroll else 1
         aux0 = jnp.zeros((), jnp.float32)
+        if cfg.residual_fp32:
+            x = x.astype(jnp.float32)
 
         if mode == "decode" and self.scan_unroll:
             # measurement mode: the xs->ys formulation — XLA cost analysis

@@ -172,9 +172,7 @@ def moe_apply(
                              token_chunks, unroll)
             return jax.lax.psum(y, m)
 
-        from repro.distributed.collectives import compat_shard_map
-
-        y = compat_shard_map(
+        y = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(token_spec, tk_spec, tk_spec, w3, w3, w_out_spec),

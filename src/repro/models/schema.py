@@ -26,14 +26,21 @@ __all__ = ["LeafSpec", "init_params", "abstract_params", "map_leaves", "leaf_ite
 
 Tree = dict[str, Any]
 
+# axes along which a leaf stacks independent matrices
+_STACK_AXES = ("layers", "experts")
+
 
 @dataclasses.dataclass(frozen=True)
 class LeafSpec:
     shape: tuple[int, ...]
     axes: tuple[str | None, ...]          # logical axis name per dim
-    init: str = "normal"                  # normal | zeros | ones | scaled
-    scale: float = 0.02
+    init: str = "normal"                  # normal | zeros | ones | scaled |
+                                          # log_uniform | dt_bias
+    scale: float = 0.02                   # std of "normal"
     dtype: str | None = None              # None -> model default
+    fan_in_dims: int = 1                  # "scaled": leading dims contracted
+    gain: float = 1.0                     # "scaled": std is gain/sqrt(fan_in)
+    bounds: tuple[float, float] = (1.0, 1.0)   # log_uniform / dt_bias range
 
     def __post_init__(self) -> None:
         if len(self.shape) != len(self.axes):
@@ -48,9 +55,23 @@ class LeafSpec:
         if self.init == "normal":
             return (jax.random.normal(key, self.shape) * self.scale).astype(dtype)
         if self.init == "scaled":  # fan-in scaled
-            fan_in = self.shape[0] if self.shape else 1
-            s = 1.0 / math.sqrt(max(fan_in, 1))
+            # a stacked leaf holds one matrix per layer (or expert): its
+            # fan-in is that matrix's, never the stack's length
+            dims = [n for n, a in zip(self.shape, self.axes)
+                    if a not in _STACK_AXES]
+            fan_in = math.prod(dims[:self.fan_in_dims])
+            s = self.gain / math.sqrt(max(fan_in, 1))
             return (jax.random.normal(key, self.shape) * s).astype(dtype)
+        lo, hi = self.bounds
+        if self.init == "log_uniform":   # log(U[lo, hi]): Mamba's A_log
+            u = jax.random.uniform(key, self.shape, minval=lo, maxval=hi)
+            return jnp.log(u).astype(dtype)
+        if self.init == "dt_bias":
+            # softplus^-1 of dt drawn log-uniform in [lo, hi] (Mamba's
+            # dt_min/dt_max), so softplus(dt_bias) starts at that dt
+            dt = jnp.exp(jax.random.uniform(key, self.shape, minval=math.log(lo),
+                                            maxval=math.log(hi)))
+            return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
         raise ValueError(f"unknown init {self.init!r}")
 
     def abstract(self, default_dtype: jnp.dtype) -> jax.ShapeDtypeStruct:

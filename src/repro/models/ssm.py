@@ -37,19 +37,27 @@ def ssm_schema(cfg: ModelConfig) -> dict[str, LeafSpec]:
     din = cfg.ssm_d_inner
     h = cfg.ssm_heads
     n = cfg.ssm_state
+    # Mamba2's published init (mamba_ssm `Mamba2`, `_init_weights`):
+    # linear and conv weights at the torch default std 1/sqrt(3 fan_in),
+    # out_proj further scaled by 1/sqrt(n_layer), dt log-uniform in
+    # [1e-3, 1e-1] through dt_bias, A uniform in [1, 16].  With larger
+    # steps and weights a deep random stack amplifies rounding chaotically.
+    lin = 3 ** -0.5
     return {
-        "w_z": LeafSpec((d, din), ("embed", "ssm_inner"), init="scaled"),
-        "w_x": LeafSpec((d, din), ("embed", "ssm_inner"), init="scaled"),
-        "w_b": LeafSpec((d, _NGROUPS * n), ("embed", None), init="scaled"),
-        "w_c": LeafSpec((d, _NGROUPS * n), ("embed", None), init="scaled"),
-        "w_dt": LeafSpec((d, h), ("embed", "ssm_heads"), init="scaled"),
-        "dt_bias": LeafSpec((h,), ("ssm_heads",), init="zeros"),
-        "a_log": LeafSpec((h,), ("ssm_heads",), init="normal", scale=0.5),
+        "w_z": LeafSpec((d, din), ("embed", "ssm_inner"), init="scaled", gain=lin),
+        "w_x": LeafSpec((d, din), ("embed", "ssm_inner"), init="scaled", gain=lin),
+        "w_b": LeafSpec((d, _NGROUPS * n), ("embed", None), init="scaled", gain=lin),
+        "w_c": LeafSpec((d, _NGROUPS * n), ("embed", None), init="scaled", gain=lin),
+        "w_dt": LeafSpec((d, h), ("embed", "ssm_heads"), init="scaled", gain=lin),
+        "dt_bias": LeafSpec((h,), ("ssm_heads",), init="dt_bias", bounds=(1e-3, 1e-1)),
+        "a_log": LeafSpec((h,), ("ssm_heads",), init="log_uniform", bounds=(1.0, 16.0)),
         "d_skip": LeafSpec((h,), ("ssm_heads",), init="ones"),
-        "conv_w": LeafSpec((cfg.ssm_conv, din), (None, "ssm_inner"), init="scaled"),
+        "conv_w": LeafSpec((cfg.ssm_conv, din), (None, "ssm_inner"), init="scaled",
+                           gain=lin),
         "conv_b": LeafSpec((din,), ("ssm_inner",), init="zeros"),
         "norm_scale": LeafSpec((din,), ("ssm_inner",), init="ones"),
-        "w_out": LeafSpec((din, d), ("ssm_inner", "embed"), init="scaled"),
+        "w_out": LeafSpec((din, d), ("ssm_inner", "embed"), init="scaled",
+                          gain=lin / math.sqrt(cfg.num_layers)),
     }
 
 

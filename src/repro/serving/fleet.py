@@ -213,7 +213,9 @@ class FleetScheduler:
             self._blocked_rid = None
 
     def _try_adopt(self, h: KVHandoff, item: Request) -> bool:
-        for rep in self.decode_pool:
+        # least-loaded first (pool order breaks ties): handoffs spread
+        # over the decode pool instead of filling its first replica
+        for rep in sorted(self.decode_pool, key=lambda r: -r.free_slots()):
             if not (rep.alive and rep.state == ACTIVE):
                 continue
             if rep.engine.pool.page_size != h.page_size:

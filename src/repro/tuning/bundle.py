@@ -813,6 +813,13 @@ class KVHandoff:
             )
         for name, (shape, dtype) in declared.items():
             arr = arrays[name]
+            if arr.dtype.kind == "V":
+                # npz keeps extension dtypes (bfloat16, fp8) as raw bytes:
+                # read them back as the dtype the manifest declares
+                try:
+                    arr = arrays[name] = arr.view(np.dtype(dtype))
+                except (TypeError, ValueError):
+                    pass   # unknown or mismatched: the check below raises
             if list(arr.shape) != list(shape) or str(arr.dtype) != dtype:
                 raise BundleFormatError(
                     f"KV handoff: array {name} is {arr.shape}/{arr.dtype}, "

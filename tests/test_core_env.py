@@ -13,6 +13,7 @@ from repro.core.env import (
     resolve_platform,
     select_devices,
 )
+from repro.core.platform import CLUSTER, LAPTOP, TPU_V5E, detect_platform  # noqa: E402
 
 
 @pytest.mark.parametrize(
@@ -55,10 +56,37 @@ def test_invalid_value_keeps_all_devices():
 
 
 def test_platform_override_and_detection():
-    assert resolve_platform({ENV_PLATFORM: "pod-v5e"}).name == "pod-v5e"
+    assert resolve_platform({ENV_PLATFORM: "tpu-v5e-1chip"}).name == "tpu-v5e-1chip"
     with pytest.raises(KeyError):
         resolve_platform({ENV_PLATFORM: "nope"})
     assert resolve_platform({}).name == "laptop"  # 1 CPU device
+
+
+class _FakeDevice:
+    """Stands in for a jax.Device: detect_platform reads only these."""
+
+    def __init__(self, platform: str, device_kind: str):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+@pytest.mark.parametrize("n", [1, 4])
+def test_detect_platform_is_the_v5e_chips_present(n):
+    p = detect_platform([_FakeDevice("tpu", "TPU v5 lite")] * n)
+    assert p.hardware is TPU_V5E
+    assert p.mesh_shape == (n,) and p.num_devices == n
+    assert p.has("pallas_kernels") and not p.has("pallas_interpret")
+    assert p.has("native_collectives") == (n > 1)
+
+
+def test_detect_platform_unknown_tpu_kind_raises():
+    with pytest.raises(KeyError, match="TPU v4"):
+        detect_platform([_FakeDevice("tpu", "TPU v4")])
+
+
+def test_detect_platform_cpu_hosts():
+    assert detect_platform([_FakeDevice("cpu", "cpu")]) is LAPTOP
+    assert detect_platform([_FakeDevice("cpu", "cpu")] * 8) is CLUSTER
 
 
 def test_native_ops_default():

@@ -3,8 +3,10 @@
 import pytest
 
 from repro.core.abi import AbiIncompatibility, AbiString
-from repro.core.platform import CLUSTER, LAPTOP, POD_V5E
+from repro.core.platform import CLUSTER, LAPTOP, TPU_V5E, chip_platform
 from repro.core.registry import ImplKind, OpImpl, OpRegistry
+
+V5E = chip_platform(TPU_V5E, 1)   # declares pallas_kernels
 
 
 def _abi(name="op", sig="s", minor=0):
@@ -22,14 +24,14 @@ def _reg_with_op():
 
 def test_swap_on_capable_platform():
     reg = _reg_with_op()
-    binding = reg.bind(["op"], POD_V5E, native=True, freeze=False)
+    binding = reg.bind(["op"], V5E, native=True, freeze=False)
     assert binding["op"]() == "native"
     assert binding.reports[0].swapped
 
 
 def test_no_swap_when_disabled():
     reg = _reg_with_op()
-    binding = reg.bind(["op"], POD_V5E, native=False, freeze=False)
+    binding = reg.bind(["op"], V5E, native=False, freeze=False)
     assert binding["op"]() == "ref"
     assert not binding.reports[0].swapped
 
@@ -51,7 +53,7 @@ def test_abi_refusal_keeps_reference():
         strict=False,
     )
     assert not ok
-    binding = reg.bind(["op"], POD_V5E, native=True, freeze=False)
+    binding = reg.bind(["op"], V5E, native=True, freeze=False)
     assert binding["op"]() == "ref"
 
 
@@ -79,7 +81,7 @@ def test_freeze_blocks_registration():
 
 def test_binding_reports_describe():
     reg = _reg_with_op()
-    binding = reg.bind(["op"], POD_V5E, native=True, freeze=False)
+    binding = reg.bind(["op"], V5E, native=True, freeze=False)
     assert "op" in binding.describe()
 
 

@@ -8,6 +8,7 @@ hard failure, and the expected token chain for any request is exact.
 All clocks are fake; every scenario is deterministic.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -87,14 +88,18 @@ def _sample_handoff():
     )
 
 
-def test_kv_handoff_round_trip():
+@pytest.mark.parametrize("dtype", ["int64", "bfloat16", "float8_e4m3fn"])
+def test_kv_handoff_round_trip(dtype):
+    """Served caches are bf16 (or fp8 when quantized): dtypes numpy only
+    knows through ml_dtypes must come back bit-exact and typed."""
     h = _sample_handoff()
+    h.arrays["kv"] = h.arrays["kv"].astype(jnp.dtype(dtype))
     out = KVHandoff.from_bytes(h.to_bytes())
     assert (out.rid, out.source, out.next_pos) == (3, "prefill-0", 11)
     assert (out.pages_used, out.page_size) == (3, 4)
     np.testing.assert_array_equal(out.arrays["kv"], h.arrays["kv"])
     np.testing.assert_array_equal(out.arrays["state"], h.arrays["state"])
-    assert out.arrays["kv"].dtype == np.int64
+    assert out.arrays["kv"].dtype == jnp.dtype(dtype)
 
 
 def test_kv_handoff_rejects_truncation_and_noise():

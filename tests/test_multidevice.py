@@ -31,14 +31,13 @@ def test_hierarchical_equals_flat_allreduce():
         from jax.sharding import PartitionSpec as P
         from repro.distributed import flat_grad_allreduce, hierarchical_grad_allreduce
 
-        from repro.distributed.collectives import compat_shard_map
-        from repro.launch.mesh import make_compat_mesh
-        mesh = make_compat_mesh((2, 4), ("pod", "data"))
+        mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         grads = {"w": jnp.arange(32, dtype=jnp.float32).reshape(8, 4),
                  "b": jnp.ones((5,), jnp.float32)}
 
         def run(fn):
-            return jax.jit(compat_shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False,
             ))(grads)
 
@@ -58,13 +57,12 @@ def test_compressed_dcn_allreduce_close_to_exact():
         from jax.sharding import PartitionSpec as P
         from repro.distributed import flat_grad_allreduce, hierarchical_grad_allreduce
 
-        from repro.distributed.collectives import compat_shard_map
-        from repro.launch.mesh import make_compat_mesh
-        mesh = make_compat_mesh((2, 4), ("pod", "data"))
+        mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         g = {"w": jnp.linspace(-1, 1, 64, dtype=jnp.float32).reshape(8, 8)}
 
         def run(fn):
-            return jax.jit(compat_shard_map(
+            return jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False,
             ))(g)
 
@@ -84,9 +82,8 @@ def test_pipeline_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed import pipeline_apply
 
-        from repro.distributed.collectives import compat_shard_map
-        from repro.launch.mesh import make_compat_mesh
-        mesh = make_compat_mesh((4,), ("pipe",))
+        mesh = jax.make_mesh((4,), ("pipe",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         S, M, mb, d = 4, 6, 3, 8
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (S, d, d)) * 0.3
@@ -119,9 +116,8 @@ def test_moe_shard_map_matches_local_path():
         # capacity cutoff C = cf*T/E depends on the local T; with data>1
         # the reference may drop different overflow rows than the
         # single-device run — documented capacity semantics, not a bug).
-        from repro.distributed.collectives import compat_shard_map
-        from repro.launch.mesh import make_compat_mesh
-        mesh = make_compat_mesh((1, 8), ("data", "model"))
+        mesh = jax.make_mesh((1, 8), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         pctx = ParallelCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
         model = build_model(cfg, pctx=pctx)
         params = model.init(jax.random.PRNGKey(0))
@@ -153,9 +149,8 @@ def test_small_mesh_train_step_compiles_and_runs():
         from repro.optim import adamw_init
 
         cfg = ARCHS["qwen2.5-14b"].reduced()
-        from repro.distributed.collectives import compat_shard_map
-        from repro.launch.mesh import make_compat_mesh
-        mesh = make_compat_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeConfig("t", 32, 4, "train")
         dep = make_deployment(cfg, shape, mesh, options=DeployOptions(donate=False))
         params = jax.device_put(dep.model.init(jax.random.PRNGKey(0)), dep.param_sharding)
@@ -170,6 +165,55 @@ def test_small_mesh_train_step_compiles_and_runs():
         print("OK", l0)
     """)
     assert "OK" in out
+
+
+def test_fleet_replica_state_on_its_own_device():
+    """Four one-device deploys behind one fleet router: every replica
+    builds its params and paged cache on the device it was deployed on,
+    and KV handoffs land on the adopting replica's device."""
+    out = _run("""
+        import jax, numpy as np
+        from repro.configs import get_config
+        from repro.core import Runtime
+        from repro.launch.serve import JaxEngine, Request
+        from repro.launch.train import make_bundle
+        from repro.serving import FleetScheduler, Replica
+
+        cfg = get_config("qwen2.5-14b").reduced()
+        bundle = make_bundle("qwen2.5-14b", reduced=True)
+        devices = jax.devices()
+        engines = {}
+
+        def factory(role, host_id):
+            rt = Runtime(host_env={})
+            container = rt.deploy(bundle, devices=[devices[host_id]])
+            eng = JaxEngine(cfg, container, slots=2, max_len=32, chunk=4,
+                            paged=True)
+            engines[host_id] = eng
+            return Replica(host_id, role, eng)
+
+        def placed(tree, dev):
+            return all(x.devices() == {dev} for x in jax.tree.leaves(tree))
+
+        fleet = FleetScheduler(factory, prefill=1, decode=3)
+        for host_id, eng in engines.items():
+            assert placed(eng.params, devices[host_id]), host_id
+            assert placed(eng.cache, devices[host_id]), host_id
+        rng = np.random.default_rng(3)
+        reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, size=n)
+                        .astype(np.int32), max_new=2)
+                for i, n in enumerate([5, 7, 3])]
+        for r in reqs:
+            assert fleet.submit(r)
+        fleet.run(max_ticks=200)
+        assert all(r.done for r in reqs)
+        assert fleet.handoffs == len(reqs)
+        for host_id, eng in engines.items():
+            assert placed(eng.cache, devices[host_id]), host_id
+        assert sum(rep.scheduler.adopted for rep in fleet.decode_pool) == 3
+        print("PLACED")
+    """, devices=4)
+    assert "PLACED" in out
 
 
 def test_elastic_restore_8_to_4_devices():

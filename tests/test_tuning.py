@@ -66,7 +66,7 @@ def test_search_prunes_and_picks_fastest():
     import time
 
     def run_with(cfg):
-        time.sleep(0.001 * cfg["a"])
+        time.sleep(0.005 * cfg["a"])   # 10 ms apart: above scheduler jitter
 
     result = search(run_with, {"a": (1, 3, 8)},
                     feasible=lambda c: c["a"] < 8, iters=1, warmup=0)
