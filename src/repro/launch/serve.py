@@ -20,7 +20,8 @@ clocks and fake engines:
 
   * `Scheduler` — pure-python continuous batching: FCFS admission from a
     bounded queue into fixed slots, a prefill/decode interleave ratio,
-    per-request accounting (TTFT, compiled-step counts).  No jax.
+    per-request accounting (TTFT, compiled-step counts).  No jax
+    computation; its phases are profiler spans (`repro.launch.tracing`).
   * `JaxEngine` — owns params/cache and the two jitted steps; counts
     every compiled-step invocation (the table7 scoreboard's honesty
     metric).
@@ -60,6 +61,7 @@ from repro.configs.base import ShapeConfig
 from repro.core import Runtime
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import DeployOptions, make_deployment
+from repro.launch.tracing import host_log, span
 from repro.launch.train import make_bundle, serving_config
 
 __all__ = ["BlockAllocator", "PagedPool", "Request", "Scheduler", "JaxEngine",
@@ -153,10 +155,12 @@ class Request:
       slot: cache row while admitted, else None.
       prefill_pos: prompt tokens ingested so far.
       next_pos: cache position the next fed token will be written to.
-      submit_t / first_token_t / finish_t: clock readings; TTFT is
-        first_token_t - submit_t (first token falls out of the final
-        prefill chunk's logits on the chunked path, out of the first
-        decode tick on the baseline path).
+      submit_t / admit_t / first_token_t / finish_t: clock readings;
+        TTFT is first_token_t - submit_t (first token falls out of the
+        final prefill chunk's logits on the chunked path, out of the
+        first decode tick on the baseline path).  admit_t is when the
+        request first took a slot, so first_token_t - admit_t is its
+        prefill phase, queued behind other slots' chunks included.
       prefill_steps / decode_steps: compiled steps this request consumed
         — the regression-pinned invariant is prefill_steps ==
         ceil(prompt_len / C) and decode_steps == max_new - 1 on the
@@ -172,6 +176,7 @@ class Request:
     prefill_pos: int = 0
     next_pos: int = 0
     submit_t: float | None = None
+    admit_t: float | None = None
     first_token_t: float | None = None
     finish_t: float | None = None
     prefill_steps: int = 0
@@ -344,6 +349,7 @@ class JaxEngine:
         self.paged = paged
         self.window = window
         self.quantize = quantize
+        host_log()
         shape = ShapeConfig("serve", max_len, slots, "decode")
         self.dep = make_deployment(
             cfg, shape, container.mesh,
@@ -410,19 +416,25 @@ class JaxEngine:
         """
         n = int(tokens.shape[0])
         if self.prefill_mode == "chunked":
-            buf = np.zeros((1, self.chunk), np.int32)
-            buf[0, :n] = tokens
-            kw = {}
-            if self.paged:
-                kw["block_row"] = jnp.asarray(self.pool.block_tables[slot])
-            if self.window is not None:
-                kw["window"] = jnp.int32(self.window)
-            logits, self.cache = self._prefill(
-                self.params, jnp.asarray(buf), self.cache,
-                jnp.int32(slot), jnp.int32(pos), jnp.int32(n), **kw,
-            )
+            with span("repro.prefill.upload"):
+                buf = np.zeros((1, self.chunk), np.int32)
+                buf[0, :n] = tokens
+                kw = {}
+                if self.paged:
+                    kw["block_row"] = jnp.asarray(self.pool.block_tables[slot])
+                if self.window is not None:
+                    kw["window"] = jnp.int32(self.window)
+                tok = jnp.asarray(buf)
+                where = (jnp.int32(slot), jnp.int32(pos), jnp.int32(n))
+            with span("repro.prefill.dispatch"):
+                logits, self.cache = self._prefill(self.params, tok, self.cache,
+                                                   *where, **kw)
             self.prefill_calls += 1
-            return np.asarray(logits[0])
+            with span("repro.prefill.wait"):
+                # the last token's row, sliced on the device behind the step
+                last = logits[0].block_until_ready()
+            with span("repro.prefill.pull"):
+                return np.asarray(last)
         # baseline: one whole-batch decode tick per prompt token
         assert n == 1
         tok = np.zeros((self.slots, 1), np.int32)
@@ -447,17 +459,21 @@ class JaxEngine:
         """One batched decode tick.  tokens (slots, 1), pos (slots,),
         active (slots,) bool; returns (slots, vocab) logits (garbage on
         inactive rows)."""
-        kw = {}
-        if self.paged:
-            kw["block_tables"] = jnp.asarray(self.pool.block_tables)
-        if self.window is not None:
-            kw["window"] = jnp.int32(self.window)
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(tokens), self.cache,
-            jnp.asarray(pos), jnp.asarray(active), **kw,
-        )
+        with span("repro.decode.upload"):
+            kw = {}
+            if self.paged:
+                kw["block_tables"] = jnp.asarray(self.pool.block_tables)
+            if self.window is not None:
+                kw["window"] = jnp.int32(self.window)
+            tok, at, act = jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active)
+        with span("repro.decode.dispatch"):
+            logits, self.cache = self._decode(self.params, tok, self.cache, at, act,
+                                              **kw)
         self.decode_calls += 1
-        return np.asarray(logits)
+        with span("repro.decode.wait"):
+            logits.block_until_ready()
+        with span("repro.decode.pull"):
+            return np.asarray(logits)
 
     # -- KV handoff (the fleet's slot migration) --------------------------
     def export_slot(self, slot: int, n_tokens: int) -> tuple[dict, int]:
@@ -671,6 +687,8 @@ class Scheduler:
             self.engine.pool.assign(slot, pages)
         req.slot = slot
         req.state = DECODING
+        if req.admit_t is None:        # a handoff keeps its first admission
+            req.admit_t = self.clock()
         self.active[slot] = req
         self.adopted += 1
         self.peak_active = max(
@@ -700,6 +718,7 @@ class Scheduler:
             req.slot = s
             req.state = PREFILLING
             req.prefill_pos = 0
+            req.admit_t = self.clock()
             self.active[s] = req
 
     # -- lifecycle helpers ------------------------------------------------
@@ -771,74 +790,95 @@ class Scheduler:
     # -- the quantum ------------------------------------------------------
     def tick(self) -> list[tuple[int, int]]:
         """Admit, prefill up to `interleave` units, one decode tick.
-        Returns the (rid, token) pairs emitted this quantum."""
-        self.ticks += 1
-        self._admit()
-        self.peak_active = max(
-            self.peak_active, sum(r is not None for r in self.active)
-        )
-        out: list[tuple[int, int]] = []
+        Returns the (rid, token) pairs emitted this quantum.  Each phase
+        is a span (`repro.launch.tracing`)."""
+        with span("repro.tick"):
+            self.ticks += 1
+            with span("repro.admit"):
+                self._admit()
+                self.peak_active = max(
+                    self.peak_active, sum(r is not None for r in self.active)
+                )
+            out: list[tuple[int, int]] = []
 
-        for _ in range(self.interleave):
-            req = min(
-                (r for r in self.active if r is not None and r.state == PREFILLING),
-                key=lambda r: r.order, default=None,
-            )
-            if req is None:
-                break
-            if self.paged and self.window is not None:
-                self._slide_window(req)
-            n = min(self.engine.prefill_unit, req.prompt_len - req.prefill_pos)
-            window = req.prompt[req.prefill_pos : req.prefill_pos + n]
-            logits = self.engine.prefill_step(req.slot, window, req.prefill_pos)
-            req.prefill_steps += 1
-            req.prefill_pos += n
-            if req.prefill_pos >= req.prompt_len:
-                req.next_pos = req.prompt_len
-                req.state = DECODING
-                if logits is not None:
-                    # chunked path: the final chunk's logits ARE the first
-                    # token — no decode tick spent re-feeding the prompt
+            for _ in range(self.interleave):
+                req = min(
+                    (r for r in self.active if r is not None and r.state == PREFILLING),
+                    key=lambda r: r.order, default=None,
+                )
+                if req is None:
+                    break
+                with span("repro.prefill"):
+                    self._prefill_unit(req, out)
+
+            decoding = [r for r in self.active if r is not None and r.state == DECODING]
+            if decoding:
+                with span("repro.decode"):
+                    logits = self._decode_batch(decoding)
+                with span("repro.sample"):
+                    for r in decoding:
+                        r.decode_steps += 1
+                        r.next_pos += 1
+                        self._emit(r, int(np.argmax(logits[r.slot])), out)
+            if self.paged:
+                with span("repro.pages"):
+                    self._sample_pages()
+            return out
+
+    def _prefill_unit(self, req: Request, out: list) -> None:
+        """One prefill work unit of `req`; its first token when the
+        prompt is done."""
+        if self.paged and self.window is not None:
+            self._slide_window(req)
+        n = min(self.engine.prefill_unit, req.prompt_len - req.prefill_pos)
+        window = req.prompt[req.prefill_pos : req.prefill_pos + n]
+        logits = self.engine.prefill_step(req.slot, window, req.prefill_pos)
+        req.prefill_steps += 1
+        req.prefill_pos += n
+        if req.prefill_pos >= req.prompt_len:
+            req.next_pos = req.prompt_len
+            req.state = DECODING
+            if logits is not None:
+                # chunked path: the final chunk's logits ARE the first
+                # token — no decode tick spent re-feeding the prompt
+                with span("repro.sample"):
                     self._emit(req, int(np.argmax(logits)), out)
-                if self.on_handoff is not None and not req.done:
-                    # prefill-pool role: decode happens on another replica
-                    self._handoff(req)
+            if self.on_handoff is not None and not req.done:
+                # prefill-pool role: decode happens on another replica
+                self._handoff(req)
 
-        decoding = [r for r in self.active if r is not None and r.state == DECODING]
-        if decoding:
-            if self.paged and self.window is not None:
-                for r in decoding:
-                    self._slide_window(r)
-            tok = np.zeros((self.engine.slots, 1), np.int32)
-            pos = np.full(self.engine.slots, self.engine.max_len - 1, np.int32)
-            act = np.zeros(self.engine.slots, bool)
+    def _decode_batch(self, decoding: list[Request]) -> np.ndarray:
+        """The batched decode step's inputs, and its logits."""
+        if self.paged and self.window is not None:
             for r in decoding:
-                # baseline seeds from the re-fed last prompt token (its
-                # prefill discarded the logits); chunked always has tokens
-                tok[r.slot, 0] = r.tokens[-1] if r.tokens else int(r.prompt[-1])
-                pos[r.slot] = r.next_pos
-                act[r.slot] = True
-            logits = self.engine.decode_step(tok, pos, act)
-            for r in decoding:
-                r.decode_steps += 1
-                r.next_pos += 1
-                self._emit(r, int(np.argmax(logits[r.slot])), out)
-        if self.paged:
-            page = self.engine.pool.page_size
-            w = self.window
-            used = 0
-            for r in self.active:
-                if r is None:
-                    continue
-                head = r.prefill_pos if r.state == PREFILLING else r.next_pos
-                written = -(-head // page)
-                if w is not None:
-                    # recycled (out-of-window) blocks no longer hold
-                    # readable tokens — count only the live window
-                    written -= max(0, head - w) // page
-                used += written
-            self.page_samples.append((self.engine.pool.allocator.used, used))
-        return out
+                self._slide_window(r)
+        tok = np.zeros((self.engine.slots, 1), np.int32)
+        pos = np.full(self.engine.slots, self.engine.max_len - 1, np.int32)
+        act = np.zeros(self.engine.slots, bool)
+        for r in decoding:
+            # baseline seeds from the re-fed last prompt token (its
+            # prefill discarded the logits); chunked always has tokens
+            tok[r.slot, 0] = r.tokens[-1] if r.tokens else int(r.prompt[-1])
+            pos[r.slot] = r.next_pos
+            act[r.slot] = True
+        return self.engine.decode_step(tok, pos, act)
+
+    def _sample_pages(self) -> None:
+        """Pages allocated against pages holding written tokens."""
+        page = self.engine.pool.page_size
+        w = self.window
+        used = 0
+        for r in self.active:
+            if r is None:
+                continue
+            head = r.prefill_pos if r.state == PREFILLING else r.next_pos
+            written = -(-head // page)
+            if w is not None:
+                # recycled (out-of-window) blocks no longer hold
+                # readable tokens — count only the live window
+                written -= max(0, head - w) // page
+            used += written
+        self.page_samples.append((self.engine.pool.allocator.used, used))
 
     @property
     def idle(self) -> bool:

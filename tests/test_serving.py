@@ -336,6 +336,29 @@ def test_ttft_accounting_with_fake_clock():
     assert req.ttft == 9.0            # 8 prefill ticks + 1 decode tick
 
 
+
+def test_admit_stamp_with_fake_clock():
+    """admit_t: when a request took its slot, between submit and first
+    token; a queued request's queue wait is admit_t - submit_t and its
+    prefill phase first_token_t - admit_t."""
+    clock = FakeClock()
+    eng = FakeEngine(slots=1, chunk=4, clock=clock)
+    sched = Scheduler(eng, clock=clock)
+    first, second = _mk(0, 8, max_new=2), _mk(1, 4, max_new=2)
+    sched.submit(first)
+    sched.submit(second)
+    _drain(sched)
+    assert (first.submit_t, first.admit_t, first.first_token_t) == (0.0, 0.0, 2.0)
+    # admitted when the first request's decode tick freed the slot
+    assert (second.submit_t, second.admit_t, second.first_token_t) == (0.0, 3.0, 4.0)
+
+    # a handoff adopted elsewhere keeps its first admission
+    sched = Scheduler(FakeEngine(slots=2, clock=clock), clock=clock)
+    handed, fresh = _mk(2, 4), _mk(3, 4)
+    handed.admit_t = 1.0
+    assert sched.adopt(handed) and sched.adopt(fresh)
+    assert handed.admit_t == 1.0 and fresh.admit_t == clock.t
+
 def test_modes_generate_identical_tokens():
     """Policy-level equivalence: with a deterministic engine both
     prefill modes must emit the same greedy chain for every request."""
