@@ -34,14 +34,23 @@ them the same kernel serves all three serving geometries:
 page *pools* of shape (P, page, KV, Dh) addressed through a per-batch
 block table (B, nblocks) — logical position p of row b lives in page
 ``block_tables[b, p // page]`` at offset ``p % page``.  The table rides
-in the same SMEM meta as kv_len/q_start (rows 2.., transposed to
-(nblocks, B)) and the k/v BlockSpec index maps resolve the physical page
+in the same SMEM meta as kv_len/q_start (below the scalar rows,
+transposed to (nblocks, B)) and the k/v BlockSpec index maps resolve the physical page
 per grid step, so the DMA itself performs the gather — the kernel body
 is unchanged, masking stays in logical coordinates.  block_k is clamped
 to divide the page (gcd) so no tile ever straddles a page boundary.
 Unallocated table entries must still hold a valid page index (the
 serving engine points them at a reserved park page): their DMAs are
 issued even when the kv_len mask discards every lane.
+
+**Layer-stacked pools** (``layer``): paged k/v may be the whole stack
+(L, P, page, KV, Dh) that the model's layer scan carries, with a traced
+layer index.  The index is one more scalar row of the SMEM meta, just
+above the block-table rows, and the k/v index maps read it as the
+leading (squeezed) block coordinate — so the DMA fetches the layer's
+pages straight from the stack and no per-layer pool is ever sliced out.
+A single pool (P, page, KV, Dh) is taken as a stack of one at layer 0,
+so every paged call has the layer row.
 
 **Sliding window** (``window``): with a traced per-batch window width W
 a third scalar row joins the SMEM meta — the *window start*
@@ -88,8 +97,9 @@ _NEG_INF = -1e30
 
 
 def _flash_kernel(
-    meta_ref,       # SMEM (2[+1], B) int32: row 0 kv_len, row 1 q_start,
-                    # then window start (windowed only), then block tables
+    meta_ref,       # SMEM (2[+1][+1+nblocks], B) int32: row 0 kv_len,
+                    # row 1 q_start, then window start (windowed only),
+                    # then layer and block tables (paged only)
     *refs,          # [scale_ref: SMEM (2, B) fp32 k/v scales, quantized
                     # only], q (1, bq, H, dh), k (1, bk, KV, dh),
                     # v (1, bk, KV, dh), out (1, bq, H, dh), then scratch:
@@ -198,7 +208,7 @@ def _flash_kernel(
 )
 def flash_attention(
     q: jnp.ndarray,                  # (B, Sq, H, Dh)
-    k: jnp.ndarray,                  # (B, Sk, KV, Dh) | paged (P, page, KV, Dh)
+    k: jnp.ndarray,                  # (B, Sk, KV, Dh) | paged ([L,] P, page, KV, Dh)
     v: jnp.ndarray,
     kv_len: jnp.ndarray | None = None,   # () or (B,) int32; None -> Sk
     q_start: jnp.ndarray | None = None,  # () or (B,) int32; None -> Sk - Sq
@@ -213,7 +223,8 @@ def flash_attention(
     config: BlockConfig | None = None,
     interpret: bool = False,
     block_tables: jnp.ndarray | None = None,  # (B, nblocks) int32 page ids
-    page_size: int | None = None,             # tokens per page; None -> k.shape[1]
+    page_size: int | None = None,             # tokens per page; None -> pool page
+    layer: jnp.ndarray | None = None,         # () int32; k/v are (L, P, page, KV, Dh)
 ) -> jnp.ndarray:
     cfg = config if config is not None else _DEFAULTS
     if block_q is None:
@@ -223,13 +234,20 @@ def flash_attention(
     b, sq, h, dh = q.shape
     paged = block_tables is not None
     if paged:
-        page = k.shape[1] if page_size is None else page_size
-        assert k.shape[1] == page, f"pool page {k.shape[1]} != page_size {page}"
+        if layer is None and k.ndim == 4:        # one pool: a stack of one
+            k, v, layer = k[None], v[None], 0
+        if layer is None or k.ndim != 5:
+            raise ValueError("layer needs layer-stacked (L, P, page, KV, Dh) pools")
+        pool_page = k.shape[2]
+        page = pool_page if page_size is None else page_size
+        assert pool_page == page, f"pool page {pool_page} != page_size {page}"
         nblocks = block_tables.shape[1]
         sk = nblocks * page                  # logical KV extent
     else:
+        if layer is not None:
+            raise ValueError("layer needs paged pools (block_tables)")
         sk = k.shape[1]
-    kv = k.shape[2]
+    kv = k.shape[-2]
     assert h % kv == 0, f"GQA requires H % KV == 0, got {h} % {kv}"
     group = h // kv
     scale = dh ** -0.5 if scale is None else scale
@@ -260,10 +278,13 @@ def flash_attention(
         # base is kv_len - sq.
         w = jnp.broadcast_to(jnp.asarray(window, jnp.int32), (b,))
         rows.append(kv_len - sq - w + 1)
-    meta = jnp.stack(rows)                       # (2 [+1], B) in SMEM
+    layer_row = len(rows)
+    if paged:
+        rows.append(jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (b,)))
+    meta = jnp.stack(rows)                       # (2 [+1] [+1], B) in SMEM
     tbl_row = len(rows)                          # first block-table meta row
     if paged:
-        # block-table rows ride below the scalar rows: meta[tbl_row+j, bi]
+        # block-table rows ride below the layer row: meta[tbl_row+j, bi]
         # is the physical page of row bi's j-th logical block
         meta = jnp.concatenate(
             [meta, block_tables.astype(jnp.int32).T], axis=0
@@ -287,10 +308,12 @@ def flash_attention(
 
         def kv_spec():
             return pl.BlockSpec(
-                (1, block_k, kv, dh),
-                # logical k-block ik lives in page meta[tbl_row + ik // bpp,
-                # bi], tile ik % bpp within it — the DMA performs the gather
-                lambda bi, iq, ik, m: (m[tbl_row + ik // bpp, bi],
+                (None, 1, block_k, kv, dh),
+                # logical k-block ik lives in layer meta[layer_row, bi],
+                # page meta[tbl_row + ik // bpp, bi], tile ik % bpp within
+                # it — the DMA performs the gather where the stack lies
+                lambda bi, iq, ik, m: (m[layer_row, bi],
+                                       m[tbl_row + ik // bpp, bi],
                                        ik % bpp, 0, 0),
             )
     else:

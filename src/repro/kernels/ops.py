@@ -123,7 +123,13 @@ _SIGS = {
 #              after the VMEM upcast; the scales are a (2, B) fp32
 #              SMEM operand beside the int32 meta
 #              (docs/quantization.md "scale meta ABI")
-_ABI_MINORS = {"moe_gmm": 2, "decode_attention": 4, "chunk_attention": 3}
+#   decode_attention 5 / chunk_attention 4: optional trailing layer arg
+#              (traced () i32) — paged k/v may be the layer-stacked pools
+#              (L, P, page, KV, Dh) a layer scan carries; the kernel grew
+#              a layer row in the same SMEM meta and reads the layer's
+#              pages from the stack, so no per-layer pool is sliced out
+#              (docs/kernels.md "Layer-stacked pools: the layer row")
+_ABI_MINORS = {"moe_gmm": 2, "decode_attention": 5, "chunk_attention": 4}
 
 ABIS: dict[str, AbiString] = {
     name: AbiString.make(name, sig, major=1, minor=_ABI_MINORS.get(name, 0))
@@ -152,47 +158,55 @@ def _ref_windowed_attention(q, k, v, window, *, scale=None):
 
 
 def _native_decode_attention(q, k_cache, v_cache, pos, block_tables=None,
-                             window=None, k_scale=None, v_scale=None, *,
-                             scale=None, config=None, interpret=False):
+                             window=None, k_scale=None, v_scale=None,
+                             layer=None, *, scale=None, config=None,
+                             interpret=False):
     # decode = flash with Sq=1 over the written prefix of the cache; with
     # block_tables the caches are page pools and the kernel's index maps
-    # gather pages (page size = the pool's second dim); with window only
+    # gather pages (page size = the pool's page dim); with window only
     # the trailing `window` slots are attended (out-of-window pages may
     # already be parked); with k_scale/v_scale the pools are int8/fp8
-    # and dequantized in-kernel after the VMEM upcast
-    page = k_cache.shape[1] if block_tables is not None else None
+    # and dequantized in-kernel after the VMEM upcast; with layer the
+    # pools are layer-stacked and the kernel reads that layer's pages
+    page = k_cache.shape[-3] if block_tables is not None else None
     return flash_attention(
         q, k_cache, v_cache, kv_len=pos + 1, causal=False, scale=scale,
         window=window, k_scale=k_scale, v_scale=v_scale, config=config,
         interpret=interpret, block_tables=block_tables, page_size=page,
+        layer=layer,
     )
 
 
 def _ref_decode_attention(q, k_cache, v_cache, pos, block_tables=None,
-                          window=None, k_scale=None, v_scale=None, *,
-                          scale=None):
+                          window=None, k_scale=None, v_scale=None,
+                          layer=None, *, scale=None):
+    if layer is not None:
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     return decode_attention_ref(q, k_cache, v_cache, pos, block_tables,
                                 window, k_scale, v_scale, scale=scale)
 
 
 def _native_chunk_attention(q, k_cache, v_cache, pos, block_tables=None,
-                            window=None, k_scale=None, v_scale=None, *,
-                            scale=None, config=None, interpret=False):
+                            window=None, k_scale=None, v_scale=None,
+                            layer=None, *, scale=None, config=None,
+                            interpret=False):
     # chunked prefill = flash with the causal diagonal re-anchored at pos:
     # query i (global position pos+i) sees cache keys <= pos+i, and the
     # kv_len mask hides slots past the chunk's own freshly written tail.
-    page = k_cache.shape[1] if block_tables is not None else None
+    page = k_cache.shape[-3] if block_tables is not None else None
     return flash_attention(
         q, k_cache, v_cache, kv_len=pos + q.shape[1], q_start=pos,
         causal=True, scale=scale, window=window, k_scale=k_scale,
         v_scale=v_scale, config=config, interpret=interpret,
-        block_tables=block_tables, page_size=page,
+        block_tables=block_tables, page_size=page, layer=layer,
     )
 
 
 def _ref_chunk_attention(q, k_cache, v_cache, pos, block_tables=None,
-                         window=None, k_scale=None, v_scale=None, *,
-                         scale=None):
+                         window=None, k_scale=None, v_scale=None,
+                         layer=None, *, scale=None):
+    if layer is not None:
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     return chunk_attention_ref(q, k_cache, v_cache, pos, block_tables,
                                window, k_scale, v_scale, scale=scale)
 
@@ -358,13 +372,13 @@ def _paged_geom(args):
     if len(args) >= 5:
         shp = getattr(args[4], "shape", None)
         if shp is not None and len(shp) == 2:
-            page = args[1].shape[1]
+            page = args[1].shape[-3]        # (P | L, P), page, KV, Dh
             return page, shp[1] * page
     return None
 
 
 def _feasible_decode(cfg, platform, args):
-    smax, dh = args[1].shape[1], args[1].shape[3]
+    smax, dh = args[1].shape[1], args[1].shape[-1]
     bk = cfg["block_k"]
     paged = _paged_geom(args)
     if paged is not None:
@@ -621,7 +635,8 @@ def _attn_cache_parts(shapes, quantized=False):
     """Normalize a decode/chunk attention bucket to its array parts.
 
     Returns ``(parts, windowed)`` where parts is [q, k_cache, v_cache]
-    (contiguous) or [q, pool_k, pool_v, block_table] (paged).  pos
+    (contiguous) or [q, pool_k, pool_v, block_table] (paged; the pools
+    are 5-d when layer-stacked).  pos
     carries no geometry — recorded as a "scalar" part (traced 0-d), a
     1-d (B,) vector (continuous batching), or absent (python int) —
     drop it whichever way it appears.  The block table is always 2-d, so
@@ -633,11 +648,19 @@ def _attn_cache_parts(shapes, quantized=False):
     ``quantized`` (the caller reads it off the bucket dtype's "+int8"/
     "+float8*" suffix — the authoritative signal, since a scale part is
     shaped exactly like a traced pos) strips the trailing k/v dequant
-    scale pair (ABI decode/1:4, chunk/1:3) before the tail parse."""
+    scale pair (ABI decode/1:4, chunk/1:3) before the tail parse.  A
+    layer-stacked pool (5-d k/v) always comes with the traced layer
+    index (ABI decode/1:5, chunk/1:4) as the very last part."""
     parts = _parse_bucket(shapes)
-    if not parts or len(parts) < 3 or any(len(p) != 4 for p in parts[:3]):
+    if (not parts or len(parts) < 3 or len(parts[0]) != 4
+            or len(parts[1]) not in (4, 5) or len(parts[2]) != len(parts[1])):
         return None
+    stacked = len(parts[1]) == 5
     tail = parts[3:]
+    if stacked:
+        if not tail or tail[-1] != ():
+            return None                  # stacked pools without a layer
+        tail = tail[:-1]
     if quantized:
         if len(tail) < 2 or any(len(p) > 1 for p in tail[-2:]):
             return None                  # scale pair missing/misshapen
@@ -651,7 +674,7 @@ def _attn_cache_parts(shapes, quantized=False):
     windowed = bool(tail) and len(tail[0]) <= 1
     if windowed:
         tail = tail[1:]
-    if tail:                             # unrecognized residue
+    if tail or (stacked and table is None):   # unrecognized residue
         return None
     return parts[:3] + ([table] if table is not None else []), windowed
 
@@ -664,13 +687,15 @@ def _synth_window(logical: int):
     return jnp.asarray(max(1, logical // 4), jnp.int32)
 
 
-def _synth_scales(parts, windowed, quantized):
-    """Optional trailing (window, k_scale, v_scale) args for a
+def _synth_tail(parts, windowed, quantized):
+    """Optional trailing (window, k_scale, v_scale, layer) args for a
     resynthesized attention bucket, in adapter positional order.  The
     scale values are representative dequant magnitudes — like the window
-    width they never reach the bucket key, only their 0-d shapes do."""
+    width they never reach the bucket key, only their 0-d shapes do; a
+    layer-stacked pool reads its last layer."""
     tail = ()
-    logical = (parts[3][1] * parts[1][1]) if len(parts) == 4 else parts[1][1]
+    logical = (parts[3][1] * parts[1][-3]) if len(parts) == 4 else parts[1][1]
+    stacked = len(parts[1]) == 5
     if windowed:
         tail += (_synth_window(logical),)
     if quantized:
@@ -678,6 +703,9 @@ def _synth_scales(parts, windowed, quantized):
             tail += (None,)              # hold the window slot
         sc = jnp.asarray(0.02, jnp.float32)
         tail += (sc, sc)
+    if stacked:
+        tail += (None,) * (3 - len(tail))   # hold the window/scale slots
+        tail += (jnp.asarray(parts[1][0] - 1, jnp.int32),)
     return tail
 
 
@@ -694,7 +722,7 @@ def _synth_decode(platform, shapes, dtype):
     k = _normal(ks[1], parts[1], kv_dt)
     v = _normal(ks[2], parts[2], kv_dt)
     if len(parts) == 4:
-        npages, page = parts[1][0], parts[1][1]
+        npages, page = parts[1][-4], parts[1][-3]
         b, nblocks = parts[3]
         bt = jax.random.randint(ks[3], (b, nblocks), 0, max(npages, 1),
                                 jnp.int32)
@@ -703,7 +731,7 @@ def _synth_decode(platform, shapes, dtype):
     else:
         logical = parts[1][1]
         args = (q, k, v, logical // 2, None)
-    tail = _synth_scales(parts, windowed, quantized)
+    tail = _synth_tail(parts, windowed, quantized)
     if tail:
         return args + tail
     return args[:4] if args[4] is None else args
@@ -727,7 +755,7 @@ def _synth_chunk(platform, shapes, dtype):
     v = _normal(ks[2], parts[2], kv_dt)
     c = parts[0][1]
     if len(parts) == 4:
-        npages, page = parts[1][0], parts[1][1]
+        npages, page = parts[1][-4], parts[1][-3]
         b, nblocks = parts[3]
         logical = nblocks * page
         if logical < c:
@@ -739,7 +767,7 @@ def _synth_chunk(platform, shapes, dtype):
     else:
         logical = parts[1][1]
         args = (q, k, v, logical // 2, None)
-    tail = _synth_scales(parts, windowed, quantized)
+    tail = _synth_tail(parts, windowed, quantized)
     if tail:
         return args + tail
     return args[:4] if args[4] is None else args

@@ -256,20 +256,38 @@ def _cache_write(buf: jnp.ndarray, upd: jnp.ndarray, pos: jnp.ndarray) -> jnp.nd
 
 
 def _paged_decode_write(
-    pool: jnp.ndarray,           # (P, page, KV, Dh)
+    pool: jnp.ndarray,           # (L, P, page, KV, Dh) layer-stacked
     upd: jnp.ndarray,            # (B, 1, KV, Dh)
     pos: jnp.ndarray,            # () or (B,) int32
     block_tables: jnp.ndarray,   # (B, nblocks) int32
+    layer: jnp.ndarray,          # () int32
 ) -> jnp.ndarray:
-    """Scatter each row's new token into its page: logical position p of
-    row b lands in page block_tables[b, p // page] at offset p % page.
-    Parked rows (table row all zeros) write into the reserved park page —
-    harmless garbage, their logits are discarded by the active mask."""
+    """Scatter each row's new token into its page of layer `layer`:
+    logical position p of row b lands in page block_tables[b, p // page]
+    at offset p % page.  Parked rows (table row all zeros) write into the
+    reserved park page — harmless garbage, their logits are discarded by
+    the active mask."""
     b = upd.shape[0]
-    page = pool.shape[1]
+    page = pool.shape[2]
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     phys = block_tables[jnp.arange(b), posv // page]
-    return pool.at[phys, posv % page].set(upd[:, 0].astype(pool.dtype))
+    return pool.at[layer, phys, posv % page].set(upd[:, 0].astype(pool.dtype))
+
+
+def _check_paged(block_tables, layer) -> None:
+    if (block_tables is None) != (layer is None):
+        raise ValueError("paged attention takes layer-stacked pools with "
+                         "block_tables and a layer index together")
+
+
+def _call_op(op, *args):
+    """Call a binding op with its trailing unused optional args left off:
+    each ABI minor added optional trailing args, and a call that needs
+    none of them stays the older minor's call."""
+    n = len(args)
+    while args[n - 1] is None:
+        n -= 1
+    return op(*args[:n])
 
 
 def attention_decode(
@@ -286,19 +304,23 @@ def attention_decode(
     real_group: tuple[int, int] | None = None,
     block_tables: jnp.ndarray | None = None,   # (B, nblocks) — paged cache
     window: jnp.ndarray | None = None,         # () or (B,) i32 sliding window
+    layer: jnp.ndarray | None = None,          # () i32 — paged only
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
     """One-token attention against the cache; writes the new k/v (self only).
 
-    With `block_tables` the cache k/v are page pools (P, page, KV, Dh)
-    shared by all slots; the write scatters through the table and the op
-    gathers through it (paged decode_attention ABI).  With `window` only
-    the trailing `window` cache slots are attended (sliding-window decode
+    With `block_tables` the cache k/v are the layer scan's whole stack of
+    page pools (L, P, page, KV, Dh), shared by all slots, and `layer`
+    picks this layer: the write scatters into that layer through the
+    table and the op gathers its pages in place (paged decode_attention
+    ABI), so no per-layer pool is copied.  With `window` only the
+    trailing `window` cache slots are attended (sliding-window decode
     ABI) — out-of-window pages may already have been released.
 
     A quantized cache carries ``"k_scale"``/``"v_scale"`` leaves (static
     per-slot calibration, () or (B,) fp32): fresh k/v are quantized onto
     the cache grid before the write and the scales ride as trailing
     binding args — the op dequantizes in-kernel (scale meta ABI)."""
+    _check_paged(block_tables, layer)
     k_scale, v_scale = cache.get("k_scale"), cache.get("v_scale")
     rope_pos = pos[None] if pos.ndim == 0 else pos[:, None]
     q = jnp.einsum("bsd,dhk->bshk", x, dequant_param(params["wq"], x.dtype))
@@ -324,23 +346,15 @@ def attention_decode(
             k = _quant_update(k, k_scale, cache["k"].dtype)
             v = _quant_update(v, v_scale, cache["v"].dtype)
         if block_tables is not None:
-            k_cache = _paged_decode_write(cache["k"], k, pos, block_tables)
-            v_cache = _paged_decode_write(cache["v"], v, pos, block_tables)
+            k_cache = _paged_decode_write(cache["k"], k, pos, block_tables,
+                                          layer)
+            v_cache = _paged_decode_write(cache["v"], v, pos, block_tables,
+                                          layer)
         else:
             k_cache = _cache_write(cache["k"], k, pos)
             v_cache = _cache_write(cache["v"], v, pos)
-        if k_scale is not None:
-            out = binding["decode_attention"](q, k_cache, v_cache, pos,
-                                              block_tables, window,
-                                              k_scale, v_scale)
-        elif window is not None:
-            out = binding["decode_attention"](q, k_cache, v_cache, pos,
-                                              block_tables, window)
-        elif block_tables is not None:
-            out = binding["decode_attention"](q, k_cache, v_cache, pos,
-                                              block_tables)
-        else:
-            out = binding["decode_attention"](q, k_cache, v_cache, pos)
+        out = _call_op(binding["decode_attention"], q, k_cache, v_cache, pos,
+                       block_tables, window, k_scale, v_scale, layer)
         new_cache = {"k": k_cache, "v": v_cache}
         if k_scale is not None:
             new_cache["k_scale"], new_cache["v_scale"] = k_scale, v_scale
@@ -364,6 +378,7 @@ def attention_chunk(
     real_group: tuple[int, int] | None = None,
     block_tables: jnp.ndarray | None = None,   # (nblocks,) — this slot's row
     window: jnp.ndarray | None = None,         # () i32 sliding window
+    layer: jnp.ndarray | None = None,          # () i32 — paged only
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
     """Chunked-prefill attention: C prompt tokens at global positions
     pos..pos+C-1 against the partially filled cache.
@@ -376,10 +391,13 @@ def attention_chunk(
     masking is needed here; the SSM path is where padding needs care.
 
     With `block_tables` (the prefilling slot's (nblocks,) table row,
-    B == 1) the cache k/v are page pools and the serving invariant
+    B == 1) the cache k/v are the layer-stacked page pools and `layer`
+    picks this layer, as in `attention_decode`; the serving invariant
     page == C makes the chunk's write exactly one page: the chunk at
-    global position pos fills page block_tables[pos // page] whole.
+    global position pos fills page block_tables[pos // page] of the layer
+    whole.
     """
+    _check_paged(block_tables, layer)
     k_scale, v_scale = cache.get("k_scale"), cache.get("v_scale")
     c = x.shape[1]
     chunk_pos = pos + jnp.arange(c)
@@ -397,36 +415,22 @@ def attention_chunk(
         k = _quant_update(k, k_scale, cache["k"].dtype)
         v = _quant_update(v, v_scale, cache["v"].dtype)
     if block_tables is not None:
-        page = cache["k"].shape[1]
+        page = cache["k"].shape[2]
         assert c == page, f"paged prefill requires chunk == page, {c} != {page}"
         blk = jax.lax.dynamic_index_in_dim(
             jnp.asarray(block_tables, jnp.int32), pos // page, keepdims=False
         )
+        at = (layer, blk, 0, 0, 0)
         k_cache = jax.lax.dynamic_update_slice(
-            cache["k"], k.astype(cache["k"].dtype), (blk, 0, 0, 0))
+            cache["k"], k[None].astype(cache["k"].dtype), at)
         v_cache = jax.lax.dynamic_update_slice(
-            cache["v"], v.astype(cache["v"].dtype), (blk, 0, 0, 0))
-        if k_scale is not None:
-            out = binding["chunk_attention"](q, k_cache, v_cache, pos,
-                                             block_tables[None], window,
-                                             k_scale, v_scale)
-        elif window is not None:
-            out = binding["chunk_attention"](q, k_cache, v_cache, pos,
-                                             block_tables[None], window)
-        else:
-            out = binding["chunk_attention"](q, k_cache, v_cache, pos,
-                                             block_tables[None])
+            cache["v"], v[None].astype(cache["v"].dtype), at)
+        block_tables = block_tables[None]
     else:
         k_cache = _cache_write(cache["k"], k, pos)
         v_cache = _cache_write(cache["v"], v, pos)
-        if k_scale is not None:
-            out = binding["chunk_attention"](q, k_cache, v_cache, pos,
-                                             None, window, k_scale, v_scale)
-        elif window is not None:
-            out = binding["chunk_attention"](q, k_cache, v_cache, pos,
-                                             None, window)
-        else:
-            out = binding["chunk_attention"](q, k_cache, v_cache, pos)
+    out = _call_op(binding["chunk_attention"], q, k_cache, v_cache, pos,
+                   block_tables, window, k_scale, v_scale, layer)
     out = _mask_padded_heads(out, real_group)
     if pctx is not None and pctx.active:
         out = pctx.constrain_heads(out)

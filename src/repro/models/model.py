@@ -348,7 +348,8 @@ class Model:
     # layer application
     # ------------------------------------------------------------------ #
     def _layer(self, j, lp, x, mode, lc, pos, enc_out, positions, aux,
-               n_valid=None, active=None, block_tables=None, window=None):
+               n_valid=None, active=None, block_tables=None, window=None,
+               layer=None):
         cfg, binding = self.cfg, self.binding
         new_cache: Tree = {}
         h = L.norm_apply(lp["pre_norm"], x, cfg, binding)
@@ -364,7 +365,7 @@ class Model:
                 y, kv = apply(
                     lp["attn"], h, attn_cache, pos, cfg, binding,
                     use_rope=self.use_rope, pctx=self.pctx, real_group=rg,
-                    block_tables=block_tables, window=window,
+                    block_tables=block_tables, window=window, layer=layer,
                 )
                 new_cache.update(kv)
             else:
@@ -493,30 +494,37 @@ class Model:
             # KV cache (the xs->ys formulation cannot alias across the
             # loop boundary; measured +5.4 GB temp on qwen2-72b decode_32k).
             # Chunked prefill reuses the same formulation: C tokens instead
-            # of 1, same in-place cache discipline.
+            # of 1, same in-place cache discipline.  Paged attention pools
+            # are never sliced per layer: the layer's k/v leaves go to the
+            # attention whole, with the block index i, which writes the new
+            # tokens into the stack and reads the layer's pages in place
+            # (slicing them would copy a whole pool out and back each layer).
             def dec_block(carry, bp):
                 x, aux, cache_st, i = carry
-                new_cache = cache_st
+                new_cache = dict(cache_st)
                 for j in range(p):
-                    lc = jax.tree.map(
-                        lambda c: jax.lax.dynamic_index_in_dim(
-                            c, i, axis=0, keepdims=False
-                        ),
-                        new_cache[f"p{j}"],
-                    )
+                    entry = new_cache[f"p{j}"]
+                    whole = (("k", "v") if block_tables is not None
+                             and cfg.is_attn_layer(j) else ())
+                    # leaves in sorted order, as jax.tree.map visits them
+                    lc = {
+                        name: (entry[name] if name in whole
+                               else jax.lax.dynamic_index_in_dim(
+                                   entry[name], i, axis=0, keepdims=False))
+                        for name in sorted(entry)
+                    }
                     x, nc, aux = self._layer(
                         j, bp[f"p{j}"], x, mode, lc, pos, enc_out, positions, aux,
                         n_valid=n_valid, active=active, block_tables=block_tables,
-                        window=window,
+                        window=window, layer=i if whole else None,
                     )
-                    new_cache = dict(new_cache)
-                    new_cache[f"p{j}"] = jax.tree.map(
-                        lambda buf, upd: jax.lax.dynamic_update_slice_in_dim(
-                            buf, upd[None].astype(buf.dtype), i, axis=0
-                        ),
-                        new_cache[f"p{j}"],
-                        nc,
-                    )
+                    new_cache[f"p{j}"] = {
+                        name: (nc[name] if name in whole
+                               else jax.lax.dynamic_update_slice_in_dim(
+                                   entry[name], nc[name][None].astype(entry[name].dtype),
+                                   i, axis=0))
+                        for name in sorted(nc)
+                    }
                 return (x, aux, new_cache, i + 1), None
 
             (x, aux, new_cache, _), _ = jax.lax.scan(

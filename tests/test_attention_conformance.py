@@ -13,6 +13,9 @@ prefill, `chunk_attention`, `decode_attention`) over
     poisoned park page),
   * sliding windows W in {page, 2*page, >= kv_len} for the windowed
     variants, with W >= kv_len pinned bit-identical to full attention,
+  * layer-stacked pools (L = 3, the layer a scan carries read at index 0
+    or 2, poison in the others) pinned bit-identical to the per-layer
+    pool, for the Pallas kernel and the reference alike,
 
 against a single fp32 masked-softmax oracle.  Every geometry is also
 round-tripped through the tuner synthesizer (`bucket_shapes` ->
@@ -36,7 +39,7 @@ from repro.kernels.flash_attention_ref import (
     decode_attention_ref,
     windowed_attention_ref,
 )
-from repro.kernels.ops import _NATIVES_INTERPRET, tuners
+from repro.kernels.ops import _NATIVES_INTERPRET, _REFS, tuners
 from repro.tuning import bucket_shapes
 from repro.tuning.config import BlockConfig
 
@@ -111,6 +114,35 @@ def _paged_layout(k, v, page, seed):
     return pool_k, pool_v, bt
 
 
+STACK_LAYERS = 3
+STACKED = ("stacked0", "stacked2")    # layouts: the pool at layer 0 / 2 of 3
+
+
+def _stack(pool, layer):
+    """The (L, P, page, KV, Dh) stack a layer scan carries, holding `pool`
+    at `layer` and poison in every other layer: a read of the wrong layer
+    is loud."""
+    layers = [pool if i == layer else jnp.full_like(pool, POISON)
+              for i in range(STACK_LAYERS)]
+    return jnp.stack(layers)
+
+
+def _assert_stacked_identical(op, layout, args):
+    """`op` on the layer-stacked pools (layer index as the trailing arg)
+    must equal `op` on the per-layer pools bit for bit, for the Pallas
+    kernel (interpret) and for the reference adapter.  `args` are the
+    per-layer call's: q, pool_k, pool_v, pos, block table[, window,
+    k_scale, v_scale]."""
+    layer = int(layout[len("stacked"):])
+    q, pool_k, pool_v, *rest = args
+    tail = rest + [None] * (5 - len(rest))
+    for impl in (_NATIVES_INTERPRET[op], _REFS[op]):
+        want = impl(*args)
+        got = impl(q, _stack(pool_k, layer), _stack(pool_v, layer), *tail,
+                   jnp.asarray(layer, jnp.int32))
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def _close(got, want, dtype, scale=1):
     tol = scale * TOLS[dtype]
     np.testing.assert_allclose(
@@ -171,6 +203,25 @@ def test_flash_paged_matches_contiguous(geom, dtype):
     _close(paged, cont, dtype)
 
 
+@pytest.mark.parametrize("case", ["no-table", "single-pool-with-layer"])
+def test_flash_layer_needs_stacked_paged_pools(case):
+    """A layer index reads a layer-stacked paged pool only: with contiguous
+    k/v, or beside a single pool (already read as a stack of one), the
+    call is refused rather than read at the wrong offset."""
+    ks = jax.random.split(jax.random.PRNGKey(_seed("flash-layer", case)), 3)
+    q = _mk(ks[0], (1, 8, 2, 8), jnp.float32)
+    k = _mk(ks[1], (1, 16, 2, 8), jnp.float32)
+    v = _mk(ks[2], (1, 16, 2, 8), jnp.float32)
+    layer = jnp.asarray(0, jnp.int32)
+    if case == "no-table":
+        args, paged = (q, k, v), {}
+    else:
+        pool_k, pool_v, bt = _paged_layout(k, v, 8, _seed("flash-layer", "pool"))
+        args, paged = (q, pool_k, pool_v), {"block_tables": bt, "page_size": 8}
+    with pytest.raises(ValueError, match="layer needs"):
+        flash_attention(*args, interpret=True, layer=layer, **paged)
+
+
 # ---------------------------------------------------------------------------
 # decode_attention: pos offsets x padding x layout
 # ---------------------------------------------------------------------------
@@ -195,11 +246,16 @@ def _decode_args(geom, dtype, tag="decode"):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", *STACKED])
 @pytest.mark.parametrize("geom", DECODE_GEOMS, ids=lambda g: f"smax{g[1]}b{g[0]}")
 def test_decode_grid(geom, layout, dtype):
     q, k, v, pos = _decode_args(geom, dtype)
     want = decode_attention_ref(q, k, v, pos)   # pinned against _oracle below
+    if layout in STACKED:
+        pool_k, pool_v, bt = _paged_layout(k, v, 8, _seed("decode", geom, dtype, "pool"))
+        _assert_stacked_identical("decode_attention", layout,
+                                  (q, pool_k, pool_v, pos, bt))
+        return
     if layout == "paged":
         page = 8
         pool_k, pool_v, bt = _paged_layout(k, v, page, _seed("decode", geom, dtype, "pool"))
@@ -242,11 +298,16 @@ def _chunk_args(geom, dtype, tag="chunk"):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", *STACKED])
 @pytest.mark.parametrize("geom", CHUNK_GEOMS, ids=lambda g: f"c{g[0]}pos{g[5]}")
 def test_chunk_grid(geom, layout, dtype):
     q, k, v, pos = _chunk_args(geom, dtype)
     want = chunk_attention_ref(q, k, v, pos)
+    if layout in STACKED:
+        pool_k, pool_v, bt = _paged_layout(k, v, geom[0], _seed("chunk", geom, dtype, "pool"))
+        _assert_stacked_identical("chunk_attention", layout,
+                                  (q, pool_k, pool_v, pos, bt))
+        return
     if layout == "paged":
         page = geom[0]                          # serving invariant: page == C
         pool_k, pool_v, bt = _paged_layout(k, v, page, _seed("chunk", geom, dtype, "pool"))
@@ -308,12 +369,25 @@ def _roundtrip(op, args, expect_feasible=True):
         assert feasible, f"{op}: no feasible config for bucket {shapes}"
 
 
+def _stacked_args(q, pool_k, pool_v, pos, bt, w=None):
+    """A layer-stacked call's positional args: the window/scale slots
+    held by None, the traced layer index last."""
+    return (q, _stack(pool_k, 2), _stack(pool_v, 2), pos, bt, w, None, None,
+            jnp.asarray(2, jnp.int32))
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", "stacked"])
 @pytest.mark.parametrize("geom", DECODE_GEOMS, ids=lambda g: f"smax{g[1]}b{g[0]}")
 def test_decode_synth_roundtrip(geom, layout, dtype):
     q, k, v, pos = _decode_args(geom, dtype)
-    if layout == "paged":
+    if layout == "stacked":
+        pool_k, pool_v, bt = _paged_layout(
+            jnp.tile(k, (1, -(-32 // k.shape[1]), 1, 1))[:, :32],
+            jnp.tile(v, (1, -(-32 // v.shape[1]), 1, 1))[:, :32], 16,
+            _seed("decode-rt", geom, dtype, "pool"))
+        _roundtrip("decode_attention", _stacked_args(q, pool_k, pool_v, pos, bt))
+    elif layout == "paged":
         page = 16                               # >= the space's smallest bk
         pool_k, pool_v, bt = _paged_layout(
             jnp.tile(k, (1, -(-32 // k.shape[1]), 1, 1))[:, :32],
@@ -389,7 +463,7 @@ def test_windowed_flash_paged_matches_contiguous(geom, wtag, dtype):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", *STACKED])
 @pytest.mark.parametrize("wtag", WINDOWS)
 @pytest.mark.parametrize("geom", DECODE_GEOMS, ids=lambda g: f"smax{g[1]}b{g[0]}")
 def test_windowed_decode_grid(geom, layout, wtag, dtype):
@@ -397,6 +471,12 @@ def test_windowed_decode_grid(geom, layout, wtag, dtype):
     smax = geom[1]
     w = _win(wtag, 8, smax)                     # full: W >= pos+1 for all rows
     want = decode_attention_ref(q, k, v, pos, None, w)
+    if layout in STACKED:
+        pool_k, pool_v, bt = _paged_layout(
+            k, v, 8, _seed("wdecode", geom, dtype, "pool"))
+        _assert_stacked_identical("decode_attention", layout,
+                                  (q, pool_k, pool_v, pos, bt, w))
+        return
     if layout == "paged":
         pool_k, pool_v, bt = _paged_layout(
             k, v, 8, _seed("wdecode", geom, dtype, "pool"))
@@ -413,7 +493,7 @@ def test_windowed_decode_grid(geom, layout, wtag, dtype):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", *STACKED])
 @pytest.mark.parametrize("wtag", WINDOWS)
 @pytest.mark.parametrize("geom", CHUNK_GEOMS, ids=lambda g: f"c{g[0]}pos{g[5]}")
 def test_windowed_chunk_grid(geom, layout, wtag, dtype):
@@ -421,6 +501,12 @@ def test_windowed_chunk_grid(geom, layout, wtag, dtype):
     c, smax = geom[0], geom[1]
     w = _win(wtag, c, smax)                     # full: W >= pos+C for all geoms
     want = chunk_attention_ref(q, k, v, pos, None, w)
+    if layout in STACKED:
+        pool_k, pool_v, bt = _paged_layout(
+            k, v, c, _seed("wchunk", geom, dtype, "pool"))
+        _assert_stacked_identical("chunk_attention", layout,
+                                  (q, pool_k, pool_v, pos, bt, w))
+        return
     if layout == "paged":
         page = c                                # serving invariant: page == C
         pool_k, pool_v, bt = _paged_layout(
@@ -502,13 +588,22 @@ def test_windowed_decode_synth_roundtrip(geom, layout, dtype):
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", "stacked"])
 @pytest.mark.parametrize("geom", CHUNK_GEOMS, ids=lambda g: f"c{g[0]}pos{g[5]}")
 def test_windowed_chunk_synth_roundtrip(geom, layout, dtype):
     q, k, v, pos = _chunk_args(geom, dtype, tag="wchunk-rt")
     w = jnp.asarray(16, jnp.int32)
     ok = geom[0] >= 16                          # see test_chunk_synth_roundtrip
-    if layout == "paged":
+    if layout == "stacked":
+        page = max(geom[0], 16)
+        s = -(-k.shape[1] // page) * page
+        pool_k, pool_v, bt = _paged_layout(
+            jnp.pad(k, ((0, 0), (0, s - k.shape[1]), (0, 0), (0, 0))),
+            jnp.pad(v, ((0, 0), (0, s - v.shape[1]), (0, 0), (0, 0))), page,
+            _seed("wchunk-rt", geom, dtype, "pool"))
+        _roundtrip("chunk_attention", _stacked_args(q, pool_k, pool_v, pos, bt, w),
+                   expect_feasible=ok)
+    elif layout == "paged":
         page = max(geom[0], 16)
         s = -(-k.shape[1] // page) * page
         pool_k, pool_v, bt = _paged_layout(

@@ -13,7 +13,8 @@ paged-layout harness) to the quantized serving path:
     against the quantized ref and enveloped against the fp32 oracle
     computed on the ORIGINAL (pre-quantization) cache;
   * bit-identity pins: W >= kv_len quantized-windowed == quantized-full,
-    paged == contiguous on identical codes;
+    paged == contiguous on identical codes, layer-stacked pools (L = 3,
+    read at layer 0 or 2) == the per-layer pool, kernel and reference;
   * tuner synthesizer round-trips for the composite "float32+int8" /
     "float32+fp8" buckets, so autotune can rebuild every quantized
     geometry the serving paths emit.
@@ -37,7 +38,7 @@ from repro.kernels.flash_attention_ref import (
     chunk_attention_ref,
     decode_attention_ref,
 )
-from repro.kernels.ops import _NATIVES_INTERPRET, tuners
+from repro.kernels.ops import _NATIVES_INTERPRET, _REFS, tuners
 from repro.kernels.quant import (
     FP8_MAX,
     INT8_MAX,
@@ -123,6 +124,30 @@ def _paged_layout(k, v, page, seed):
     return pool_k, pool_v, bt
 
 
+STACK_LAYERS = 3
+STACKED = ("stacked0", "stacked2")    # layouts: the pool at layer 0 / 2 of 3
+
+
+def _stack(pool, layer):
+    """(L, P, page, KV, Dh) stack holding the quantized `pool` at `layer`
+    and poison codes elsewhere (see test_attention_conformance._stack)."""
+    return jnp.stack([pool if i == layer else jnp.full_like(pool, POISON)
+                      for i in range(STACK_LAYERS)])
+
+
+def _assert_stacked_identical(op, layout, args):
+    """The layer-stacked call (layer index trailing) == the per-layer call
+    bit for bit, Pallas kernel (interpret) and reference adapter alike.
+    `args`: q, pool_k, pool_v, pos, table, window, k_scale, v_scale."""
+    layer = int(layout[len("stacked"):])
+    q, pool_k, pool_v, *rest = args
+    for impl in (_NATIVES_INTERPRET[op], _REFS[op]):
+        want = impl(*args)
+        got = impl(q, _stack(pool_k, layer), _stack(pool_v, layer), *rest,
+                   jnp.asarray(layer, jnp.int32))
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 # ---------------------------------------------------------------------------
 # quant_matmul: ragged geometry x format, kernel == ref, ref ~ fp32
 # ---------------------------------------------------------------------------
@@ -200,13 +225,19 @@ def _decode_args(geom, fmt, tag="qdecode"):
 
 @pytest.mark.parametrize("fmt", FORMATS)
 @pytest.mark.parametrize("wtag", WINDOWS)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", *STACKED])
 @pytest.mark.parametrize("geom", DECODE_GEOMS, ids=lambda g: f"smax{g[1]}b{g[0]}")
 def test_quant_decode_grid(geom, layout, wtag, fmt):
     q, k, v, qk, qv, ks_, vs_, pos = _decode_args(geom, fmt)
     smax = geom[1]
     w = jnp.asarray(8 if wtag == "win" else smax, jnp.int32)
     want = decode_attention_ref(q, k, v, pos, None, w)   # fp32 oracle
+    if layout in STACKED:
+        pool_k, pool_v, bt = _paged_layout(
+            qk, qv, 8, _seed("qdecode", geom, fmt, "pool"))
+        _assert_stacked_identical("decode_attention", layout,
+                                  (q, pool_k, pool_v, pos, bt, w, ks_, vs_))
+        return
     if layout == "paged":
         pool_k, pool_v, bt = _paged_layout(
             qk, qv, 8, _seed("qdecode", geom, fmt, "pool"))
@@ -298,13 +329,19 @@ def _chunk_args(geom, fmt, tag="qchunk"):
 
 @pytest.mark.parametrize("fmt", FORMATS)
 @pytest.mark.parametrize("wtag", WINDOWS)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", *STACKED])
 @pytest.mark.parametrize("geom", CHUNK_GEOMS, ids=lambda g: f"c{g[0]}pos{g[5]}")
 def test_quant_chunk_grid(geom, layout, wtag, fmt):
     q, k, v, qk, qv, ks_, vs_, pos = _chunk_args(geom, fmt)
     c, smax = geom[0], geom[1]
     w = jnp.asarray(c if wtag == "win" else smax, jnp.int32)
     want = chunk_attention_ref(q, k, v, pos, None, w)    # fp32 oracle
+    if layout in STACKED:
+        pool_k, pool_v, bt = _paged_layout(
+            qk, qv, c, _seed("qchunk", geom, fmt, "pool"))
+        _assert_stacked_identical("chunk_attention", layout,
+                                  (q, pool_k, pool_v, pos, bt, w, ks_, vs_))
+        return
     if layout == "paged":
         page = c                                # serving invariant: page == C
         pool_k, pool_v, bt = _paged_layout(
@@ -357,11 +394,20 @@ def _roundtrip(op, args, expect_feasible=True):
 
 
 @pytest.mark.parametrize("fmt", FORMATS)
-@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+@pytest.mark.parametrize("layout", ["contiguous", "paged", "stacked"])
 @pytest.mark.parametrize("geom", DECODE_GEOMS, ids=lambda g: f"smax{g[1]}b{g[0]}")
 def test_quant_decode_synth_roundtrip(geom, layout, fmt):
     q, _, _, qk, qv, ks_, vs_, pos = _decode_args(geom, fmt, tag="qd-rt")
-    if layout == "paged":
+    if layout == "stacked":
+        s = -(-qk.shape[1] // 16) * 16
+        pad = ((0, 0), (0, s - qk.shape[1]), (0, 0), (0, 0))
+        pool_k, pool_v, bt = _paged_layout(
+            jnp.pad(qk, pad), jnp.pad(qv, pad), 16,
+            _seed("qd-rt", geom, fmt, "pool"))
+        _roundtrip("decode_attention",
+                   (q, _stack(pool_k, 2), _stack(pool_v, 2), pos, bt, None,
+                    ks_, vs_, jnp.asarray(2, jnp.int32)))
+    elif layout == "paged":
         page = 16                               # >= the space's smallest bk
         s = -(-qk.shape[1] // page) * page
         pad = ((0, 0), (0, s - qk.shape[1]), (0, 0), (0, 0))

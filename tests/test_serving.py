@@ -15,6 +15,7 @@ Three layers, cheapest first:
     single-request reference.
 """
 
+import dataclasses
 import math
 
 import jax
@@ -133,6 +134,58 @@ def test_chunked_prefill_matches_prefill_by_decode(arch):
         logits, cache = decode(params, jnp.asarray(tok), cache,
                                jnp.asarray(pos), jnp.asarray(act))
     np.testing.assert_allclose(np.asarray(logits[0]), got, atol=5e-4, rtol=5e-4)
+
+
+# Two blocks of a period-2 layer pattern: MoE every second layer (two
+# attention positions), and the hybrid's attention next to SSM.  Each
+# position's stacked leaves are read and written at block index i, the
+# paged k/v pools in place by the attention kernels.
+PERIOD_TWO = {
+    "moe-every-2": lambda: dataclasses.replace(
+        ARCHS["moonshot-v1-16b-a3b"].reduced(), moe_every=2, num_layers=4),
+    "hybrid": lambda: ARCHS["jamba-1.5-large-398b"].reduced(),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(PERIOD_TWO))
+def test_paged_steps_match_contiguous_with_period_two(arch):
+    """prefill_into and decode through shuffled block tables give exactly
+    the contiguous cache's logits, chunk by chunk and step by step."""
+    model = build_model(PERIOD_TWO[arch]())
+    assert (model.period, model.num_blocks) == (2, 2)
+    params = model.init(jax.random.PRNGKey(0))
+    slots, chunk, max_len = 2, 4, 16
+    nb = max_len // chunk
+    num_pages = 1 + slots * nb
+    tables = np.random.default_rng(0).permutation(
+        np.arange(1, num_pages)).reshape(slots, nb).astype(np.int32)
+    cont = model.init_cache(slots, max_len)
+    paged = model.init_paged_cache(num_pages, chunk, slots)
+    prefill, decode = jax.jit(model.prefill_into), jax.jit(model.decode)
+    lengths = (10, 7)
+    tok = np.zeros((slots, 1), np.int32)
+    for slot, n_prompt in enumerate(lengths):
+        prompt = np.asarray(jax.random.randint(
+            jax.random.PRNGKey(10 + slot), (n_prompt,), 0, model.cfg.vocab_size))
+        for start in range(0, n_prompt, chunk):
+            n = min(chunk, n_prompt - start)
+            buf = np.zeros((1, chunk), np.int32)
+            buf[0, :n] = prompt[start:start + n]
+            where = (jnp.int32(slot), jnp.int32(start), jnp.int32(n))
+            want, cont = prefill(params, jnp.asarray(buf), cont, *where)
+            got, paged = prefill(params, jnp.asarray(buf), paged, *where,
+                                 block_row=jnp.asarray(tables[slot]))
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        tok[slot, 0] = int(np.argmax(want[0]))
+    pos = np.asarray(lengths, np.int32)
+    act = jnp.ones((slots,), bool)
+    for _ in range(3):
+        want, cont = decode(params, jnp.asarray(tok), cont, jnp.asarray(pos), act)
+        got, paged = decode(params, jnp.asarray(tok), paged, jnp.asarray(pos), act,
+                            block_tables=jnp.asarray(tables))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        tok = np.asarray(jnp.argmax(want, axis=-1), np.int32)[:, None]
+        pos = pos + 1
 
 
 # ---------------------------------------------------------------------------

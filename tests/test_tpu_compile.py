@@ -141,3 +141,63 @@ def test_granite_decode_step_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, total
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """The optimized HLO's computations by name, each as its instruction
+    lines; the entry computation is keyed "ENTRY"."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            name = "ENTRY" if line.startswith("ENTRY") else line.split()[0]
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line.strip())
+    return comps
+
+
+# (step, slots, pages, max_len): the two granite cells' paged steps
+PAGED_STEPS = [("decode", 16, 193, 1536), ("prefill_into", 8, 233, 3712)]
+
+
+@pytest.mark.parametrize("step,slots,pages,max_len", PAGED_STEPS,
+                         ids=[s[0] for s in PAGED_STEPS])
+def test_granite_paged_step_keeps_pools_whole(one_chip, step, slots, pages,
+                                              max_len):
+    """The layer scan hands the attention kernels the stacked k/v pools
+    and a layer index: the compiled step holds no single layer's pool
+    (no slice of it out of the stack, no write-back), and the stack is
+    never copied inside the loop (the entry copies of the undonated
+    argument are another matter)."""
+    cfg = dataclasses.replace(get_config("granite-3-8b"), num_layers=SMOKE_LAYERS)
+    model = Model(cfg, binding=dict(NAT))
+    nb = max_len // PAGE
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+            tree)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = placed(model.abstract_params())
+    cache = placed(model.abstract_paged_cache(pages, PAGE, slots))
+    if step == "decode":
+        args = (sd((slots, 1), I32), cache, sd((slots,), I32),
+                sd((slots,), jnp.bool_), sd((slots, nb), I32))
+    else:
+        args = (sd((1, PAGE), I32), cache, sd((), I32), sd((), I32),
+                sd((), I32), sd((nb,), I32))
+    hlo = jax.jit(getattr(model, step)).lower(params, *args).compile().as_text()
+    layer_pool = f"bf16[{pages},{PAGE},{KV},{DH}]"
+    stack = f"bf16[{SMOKE_LAYERS},{pages},{PAGE},{KV},{DH}]"
+    comps = _computations(hlo)
+    assert "ENTRY" in comps and len(comps) > 1
+    for name, lines in comps.items():
+        for line in lines:
+            result = line.split(" = ", 1)[-1]
+            assert not result.startswith(layer_pool), (name, line[:200])
+            if name != "ENTRY":
+                assert not (result.startswith(stack) and " copy(" in result), \
+                    (name, line[:200])
